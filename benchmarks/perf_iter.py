@@ -5,15 +5,17 @@ override, records the three roofline terms before/after, and appends to
 results/perf/<cell>.json.  The EXPERIMENTS.md §Perf log is generated
 from these records.
 
-    PYTHONPATH=src python -m benchmarks.perf_iter --arch olmo_1b \
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m benchmarks.perf_iter --arch olmo_1b \
         --shape prefill_32k --variant causal_skip
 """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+from repro.utils import force_host_devices
+
+force_host_devices(512)
 
 import argparse      # noqa: E402
 import dataclasses   # noqa: E402
 import json          # noqa: E402
+import os            # noqa: E402
 
 from repro.configs.base import SHAPES, get_config  # noqa: E402
 from repro.launch import analytic, hlo_analysis    # noqa: E402

@@ -15,13 +15,15 @@ and compiled on the single-pod (16,16) mesh with the corpus sharded over
   hybrid_spmd  the full hybrid algorithm (density split + fail lanes) as
                one SPMD program — the faithful-paper cell
 
-    PYTHONPATH=src python -m benchmarks.perf_knn --variant baseline
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m benchmarks.perf_knn --variant baseline
 """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+from repro.utils import force_host_devices
+
+force_host_devices(512)
 
 import argparse   # noqa: E402
 import json       # noqa: E402
+import os         # noqa: E402
 
 import jax        # noqa: E402
 import jax.numpy as jnp  # noqa: E402

@@ -18,9 +18,11 @@ from benchmarks import (  # noqa: E402
     fig6_refimpl_scaling, fig7_brute, fig11_vs_k, overload, recall,
     serving, table3_granularity, table4_param_grid, table5_rho_model,
     table6_sampled_params)
+from repro.utils import init_compile_cache  # noqa: E402
 
 
 def main():
+    init_compile_cache()
     ap = common.parser("benchmarks.run")
     ap.add_argument("--quick", action="store_true",
                     help="tiny datasets (CI smoke)")

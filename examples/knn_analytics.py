@@ -16,9 +16,11 @@ import numpy as np
 from repro.core import HybridConfig, HybridKNNJoin, refimpl_knn, \
     self_join_brute
 from repro.data import pointclouds
+from repro.utils import init_compile_cache
 
 
 def main():
+    init_compile_cache()
     ds = sys.argv[1] if len(sys.argv) > 1 else "susy"
     k = int(sys.argv[2]) if len(sys.argv) > 2 else 5
     pts = pointclouds.load(ds, n_override=6000)

@@ -34,9 +34,11 @@ from repro.core.hybrid import HybridConfig
 from repro.launch.serve import generate
 from repro.models import IndexRetriever, init_params
 from repro.runtime.server import ServerConfig
+from repro.utils import init_compile_cache
 
 
 def main():
+    init_compile_cache()
     cfg = dataclasses.replace(
         get_smoke_config("olmo_1b"),
         retrieval=RetrievalConfig(enabled=True, k=8, lam=0.9,

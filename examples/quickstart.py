@@ -24,9 +24,11 @@ from repro.core import HybridConfig
 from repro.runtime import KNNIndex
 
 from repro.data import pointclouds
+from repro.utils import init_compile_cache
 
 
 def main():
+    init_compile_cache()
     # A cloud with the paper's density structure: dense cores (the GPU's
     # work in the paper; the MXU tile join here) + sparse background (the
     # CPU's work; the pyramid engine here).

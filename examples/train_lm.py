@@ -13,9 +13,11 @@ import dataclasses
 
 from repro.configs.base import get_config
 from repro.launch import train as train_mod
+from repro.utils import init_compile_cache
 
 
 def main():
+    init_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=150)
     ap.add_argument("--batch", type=int, default=4)

@@ -22,11 +22,11 @@ Three execution backends share those semantics (DESIGN.md §2.5, §2.6):
 
   * ``"ref"`` — per-query gather + broadcast-subtract (the original jnp
     path; VPU-bound, kept as the correctness oracle);
-  * ``"pallas"`` / ``"interpret"`` — the cell-tiled MXU path: queries are
+  * ``"pallas"`` / ``"interpret"`` — the cell-tiled path: queries are
     sorted by home cell (``grid.group_queries_by_cell``) so each tile
     shares ONE deduplicated 3^m candidate block
-    (``grid.tile_shared_candidates``), and the distance tile is a
-    (TQ×D)·(D×TC) matmul through the fused ``pairwise_l2`` kernel with
+    (``grid.tile_shared_candidates``), and the (TQ, TC) distance tile
+    comes from the ``pairwise_l2`` kernel (direct differences) with
     the SHORTC ε² tile short-circuit, followed by a second top-K pass
     over the materialized (TQ, TC) tile;
   * ``"fused"`` — the streaming one-pass engine (``kernels/knn_stream``):
@@ -62,6 +62,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import grid as grid_lib
+from repro.kernels import f32_precision
 from repro.kernels.knn_stream import kernel as stream_kernel
 from repro.kernels.knn_stream import ops as stream_ops
 from repro.kernels.pairwise_l2 import ops as pairwise_ops
@@ -166,7 +167,8 @@ def _block_fn(index: grid_lib.GridIndex, points_r, eps2, k, budget,
         qpts = queries[safe]                                       # (B, n)
 
         if metric == "ip":
-            d2 = -jnp.einsum("bn,bcn->bc", qpts, cand_pts)         # (B, budget)
+            d2 = -jnp.einsum("bn,bcn->bc", qpts, cand_pts,
+                             precision=f32_precision(qpts.dtype))                # (B, budget)
         else:
             diff = qpts[:, None, :] - cand_pts
             d2 = jnp.sum(diff * diff, axis=-1)                     # (B, budget)
@@ -311,7 +313,7 @@ def _rescore_fp32(points_r, qpts, ki, eps2, k, metric="l2"):
     cand = points_r[safe]                                  # (Q, k_run, n)
     q = qpts.astype(jnp.float32)
     if metric == "ip":
-        d = -jnp.einsum("qn,qcn->qc", q, cand)
+        d = -jnp.einsum("qn,qcn->qc", q, cand, precision=f32_precision(q.dtype))
     else:
         diff = q[:, None, :] - cand
         d = jnp.sum(diff * diff, axis=-1)
@@ -390,7 +392,7 @@ def _tile_fn(index: grid_lib.GridIndex, points_r, eps2, k, budget, block_c,
              kernel_mode, queries_r=None, qcoords=None, exclude_self=True,
              metric="l2"):
     """Process one cell-sorted query tile against its shared candidate
-    block (−1 = padding).  The distance tile is one MXU matmul."""
+    block (−1 = padding).  The distance tile is one kernel call."""
     cand_budget = round_up(budget, block_c)
 
     def fn(qids):

@@ -76,8 +76,8 @@ def build_shard_indices(
     leaf ``[p]`` to obtain shard p's host-side ``GridIndex``/``Pyramid``.
 
     All shards share one ε (grid geometry then differs only through
-    each shard's extent), so the per-shard engines compile ONCE and
-    serve every shard — the whole point of the equal-shape partition.
+    each shard's extent), so every shard's engines have the same shapes
+    — one compile per shard device, no shape-driven recompiles.
     """
     axes = tuple(axis_names)
     eps = jnp.float32(epsilon)
@@ -91,7 +91,7 @@ def build_shard_indices(
         return jax.tree_util.tree_map(lambda x: x[None], (g, pyr))
 
     spec = P(axes)
-    fn = utils.shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh, in_specs=(spec,), out_specs=spec, check_vma=False,
     )
     return jax.jit(fn)(points_stacked)
@@ -217,7 +217,7 @@ def collective_topk_merge(
         return run_d, run_i
 
     spec = P(axes)
-    fn = utils.shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec, spec, P()),
         out_specs=(P(), P()),
@@ -279,10 +279,10 @@ def ring_self_join(
     def local(qpts, qids, cpts, cids):
         # qpts (q_loc, n); cpts (c_loc, n) — resident shard, rotates.
         # pcast: the running buffers are device-varying from step 1 on.
-        run_d = utils.pcast(
+        run_d = jax.lax.pcast(
             jnp.full((qpts.shape[0], k), jnp.inf, jnp.float32), axes, to="varying"
         )
-        run_i = utils.pcast(
+        run_i = jax.lax.pcast(
             jnp.full((qpts.shape[0], k), -1, jnp.int32), axes, to="varying"
         )
         c_loc = cpts.shape[0]
@@ -313,7 +313,7 @@ def ring_self_join(
         return rd, ri
 
     spec = P(axes)
-    shard_fn = utils.shard_map(
+    shard_fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec, spec, spec, spec),
         out_specs=(spec, spec),
@@ -361,10 +361,10 @@ def ring_self_join_bf16(
     ring = [(i, (i + 1) % n_shards) for i in range(n_shards)]
 
     def local(qpts, qids, cpts, cids):
-        run_d = utils.pcast(
+        run_d = jax.lax.pcast(
             jnp.full((qpts.shape[0], k), jnp.inf, jnp.float32), axes,
             to="varying")
-        run_i = utils.pcast(
+        run_i = jax.lax.pcast(
             jnp.full((qpts.shape[0], k), -1, jnp.int32), axes, to="varying")
         wire = jax.lax.bitcast_convert_type(
             cpts.astype(jnp.bfloat16), jnp.int16)     # opaque wire format
@@ -395,7 +395,7 @@ def ring_self_join_bf16(
         return rd, ri
 
     spec = P(axes)
-    shard_fn = utils.shard_map(
+    shard_fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec, spec, spec, spec),
         out_specs=(spec, spec))
@@ -572,7 +572,7 @@ def hybrid_join_spmd(
         return SPMDJoinResult(out_d, out_i, out_s, unresolved)
 
     spec_q = P(axes)
-    shard_fn = utils.shard_map(
+    shard_fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), spec_q, P()),
         out_specs=SPMDJoinResult(spec_q, spec_q, spec_q, P()),

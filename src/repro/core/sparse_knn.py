@@ -21,16 +21,15 @@ The engine serves self-joins and foreign (R≠S) queries alike: with
 reordered), per-level cell coords are computed on the fly, and
 candidates always gather from the indexed reference (DESIGN.md §3).
 
-``backend=`` selects the distance formulation (DESIGN.md §2.5, §2.6):
-``"ref"`` keeps the broadcast-subtract oracle; the ``"pallas"`` /
-``"interpret"`` backends compute the same d² as a batched MXU
-dot_general over the gathered per-query operands (candidate sets here
-are per-query by design, so the dense engine's shared-candidate Pallas
-tiling does not apply); ``"fused"`` streams the candidate budget in
-chunks through a scan that carries a per-query running top-K (the
-``knn_topk`` merge helper), so neither the (B, budget, n) gathered
-operand nor the (B, budget) distance tile is ever materialized — the
-jnp-level analogue of the dense engine's streaming kernel.
+``backend=`` selects the candidate pass (DESIGN.md §2.5, §2.6): every
+backend but ``"fused"`` gathers the (B, budget, n) per-query candidate
+operand and scores it in one go (candidate sets here are per-query by
+design, so the dense engine's shared-candidate Pallas tiling does not
+apply); ``"fused"`` streams the candidate budget in chunks through a
+scan that carries a per-query running top-K (the ``knn_topk`` merge
+helper), so neither the (B, budget, n) gathered operand nor the
+(B, budget) distance tile is ever materialized — the jnp-level analogue
+of the dense engine's streaming kernel.
 """
 from __future__ import annotations
 
@@ -42,6 +41,7 @@ import jax.numpy as jnp
 
 from repro.core import dense_join as dense_lib
 from repro.core import grid as grid_lib
+from repro.kernels import f32_precision
 from repro.kernels.knn_topk import ops as topk_ops
 from repro.utils import round_up
 
@@ -78,37 +78,26 @@ class SparseKNNResult(NamedTuple):
     total_candidates: jnp.ndarray  # (Q,) i32 — work proxy (T₁ numerator)
 
 
-def _gathered_sq_l2(qpts, cand_pts, backend, metric="l2"):
+def _gathered_sq_l2(qpts, cand_pts, metric="l2"):
     """(B, n) queries vs per-query (B, C, n) candidates -> (B, C) scores
     (squared L2, or −q·c under ``metric="ip"``).
 
-    ``"ref"`` keeps the broadcast-subtract oracle.  The kernel backends use
-    the matmul identity ‖q‖² + ‖c‖² − 2·q·cᵀ as a *batched* dot_general —
-    the candidate operands differ per query (this engine exists for
-    irregular low-density work), so the shared-tile Pallas kernel does not
-    apply, but the inner product still lands on the MXU and nothing of
-    shape (B, C, n) is ever materialized."""
+    L2 is summed from direct differences: the candidate operands differ
+    per query (this engine exists for irregular low-density work), so
+    they are gathered at this shape anyway, and the matmul expansion
+    ‖q‖² + ‖c‖² − 2·q·c would cancel away the f32 digits of close
+    neighbors' distances.  Inner products take a batched MXU
+    dot_general.  Either way every score is an exact-f32 function of
+    the (possibly bf16-cast) operands."""
     if metric == "ip":
         return -jax.lax.dot_general(
             qpts, cand_pts, (((1,), (2,)), ((0,), (0,))),
+            precision=f32_precision(qpts.dtype),
             preferred_element_type=jnp.float32,
         )                                                      # (B, C)
-    if backend == "ref":
-        diff = qpts[:, None, :] - cand_pts
-        return jnp.sum(diff * diff, axis=-1)
-    # Norm terms upcast first (bf16→f32 is exact) while the dot consumes
-    # the stored dtype, so under distance_dtype="bf16" every score is an
-    # exact-f32 function of the bf16-cast operands — same contract as
-    # the dense streaming kernel.
-    qf = qpts.astype(jnp.float32)
-    cf = cand_pts.astype(jnp.float32)
-    qq = jnp.sum(qf * qf, axis=-1)[:, None]                   # (B, 1)
-    cc = jnp.sum(cf * cf, axis=-1)                            # (B, C)
-    qc = jax.lax.dot_general(
-        qpts, cand_pts, (((1,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )                                                          # (B, C)
-    return jnp.maximum(qq + cc - 2.0 * qc, 0.0)
+    diff = (qpts[:, None, :].astype(jnp.float32)
+            - cand_pts.astype(jnp.float32))
+    return jnp.sum(diff * diff, axis=-1)
 
 
 def _streamed_topk(points_r, qpts, cand_ids, keep, k, metric="l2"):
@@ -132,7 +121,7 @@ def _streamed_topk(points_r, qpts, cand_ids, keep, k, metric="l2"):
         # The chunk inherits the query dtype: under the bf16 trade the
         # caller passes bf16 queries and the gathered rows cast to match.
         pts_c = points_r[ids_c].astype(qpts.dtype)             # (B, chunk, n)
-        d2 = _gathered_sq_l2(qpts, pts_c, "interpret", metric)  # batched MXU
+        d2 = _gathered_sq_l2(qpts, pts_c, metric)
         d2m = jnp.where(keep_c, d2, jnp.inf)
         idm = jnp.where(keep_c, ids_c, -1)
         return topk_ops.merge_running_topk(
@@ -187,7 +176,7 @@ def _query_level(pyr: Pyramid, points_r, queries, orders, starts, counts,
         cand_pts = points_r[cand_ids]                         # (B, budget, n)
         if lowp:
             cand_pts = cand_pts.astype(jnp.bfloat16)
-        d2 = _gathered_sq_l2(qk, cand_pts, backend, metric)
+        d2 = _gathered_sq_l2(qk, cand_pts, metric)
         d2m = jnp.where(keep, d2, jnp.inf)
         neg, selk = jax.lax.top_k(-d2m, k_run)
         kd = -neg

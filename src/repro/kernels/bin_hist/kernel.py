@@ -2,7 +2,9 @@
 
 Implements the paper's §V-C2 sampling kernel: for S sampled query points vs
 the full database, bin each distance d < n_bins·bin_width into
-floor(d / bin_width).  Distance tiles come off the MXU (matmul form); the
+floor(d / bin_width).  Distance tiles come off the MXU (matmul form):
+its f32 cancellation (DESIGN.md §11.2) only blurs bin edges, and the ε
+it selects steers the work split, never which answers are exact.  The
 per-tile histogram is a branch-free chunked one-hot reduction; grid steps
 accumulate into a single (1, n_bins) output block ("arbitrary" semantics ⇒
 sequential revisiting, no race).
@@ -16,8 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro.kernels import f32_precision
 
 
 def _hist_kernel(q_ref, c_ref, qid_ref, cid_ref, bw_ref, out_ref, *, n_bins: int):
@@ -33,7 +34,9 @@ def _hist_kernel(q_ref, c_ref, qid_ref, cid_ref, bw_ref, out_ref, *, n_bins: int
     qq = jnp.sum(q * q, axis=1, keepdims=True)
     cc = jnp.sum(c * c, axis=1, keepdims=True).T
     qc = jax.lax.dot_general(
-        q, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, c, (((1,), (1,)), ((), ())),
+        precision=f32_precision(q.dtype),
+        preferred_element_type=jnp.float32,
     )
     d2 = jnp.maximum(qq + cc - 2.0 * qc, 0.0)
     d = jnp.sqrt(d2)
@@ -89,7 +92,7 @@ def distance_bin_histogram(
         ],
         out_specs=pl.BlockSpec((1, n_bins), lambda i, j: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, n_bins), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,

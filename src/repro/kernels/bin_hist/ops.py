@@ -6,15 +6,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import use_pallas
 from repro.utils import round_up
 from repro.kernels.bin_hist import kernel as _kernel
 from repro.kernels.bin_hist import ref as _ref
-
-
-def _use_pallas(mode: str) -> bool:
-    if mode == "auto":
-        return jax.default_backend() == "tpu"
-    return mode in ("pallas", "interpret")
 
 
 @functools.partial(
@@ -44,7 +39,7 @@ def distance_bin_histogram(
     pid = jnp.arange(n, dtype=jnp.int32)
     bw = jnp.asarray(bin_width, jnp.float32)
 
-    if not _use_pallas(mode):
+    if not use_pallas(mode):
         return _ref.distance_bin_histogram_ref(
             queries, points, qid, pid, bw, n_bins=n_bins
         )

@@ -14,8 +14,9 @@ force).  Here the candidate axis is an *inner grid dimension* instead:
   behind the current step's compute (the FlashAttention streaming
   structure).
 
-Each step computes one `(TQ×D)·(D×TCsub)` MXU distance sub-tile into
-VMEM and merges it into a per-query running top-K — distances *and*
+Each step computes one `(TQ, TCsub)` distance sub-tile in VMEM (direct
+differences for L2, ``knn_topk.sq_l2_direct``; the MXU for inner
+products) and merges it into a per-query running top-K — distances *and*
 candidate ids — carried in VMEM scratch.  Nothing of shape `(TQ, C)`
 ever exists in any memory: HBM traffic is O(Q·D + C·D + Q·K) and the
 candidate budget stops being a peak-memory knob.
@@ -30,8 +31,8 @@ Folded into the same pass (no second sweep over distances):
     (self excluded) accumulates in scratch, so the dense engine's §V-E
     failure test (`found < K`) needs no second distance sweep.
 
-The running merge is the same branch-free K min-passes as
-``knn_topk._tile_topk`` (min, first-argmin via min-iota, one-hot
+The running merge is the branch-free K min-passes network shared with
+``knn_topk`` (``topk_min_passes``: min, first-argmin via min-iota, one-hot
 knockout) applied to the running buffer concatenated with the fresh
 sub-tile along lanes — no in-kernel sort/top_k primitives.
 """
@@ -45,29 +46,14 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.knn_topk.kernel import MAX_UNROLLED_K  # shared ceiling
-
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro.kernels import f32_precision
+from repro.kernels.knn_topk.kernel import (  # shared with the tile kernel
+    MAX_UNROLLED_K,
+    sq_l2_direct,
+    topk_min_passes,
+)
 
 _INF = np.float32(np.inf)
-
-
-def _merge_topk(d: jnp.ndarray, ids: jnp.ndarray, k: int):
-    """K smallest per row of ``d`` (TQ, M) with their ids: K passes of
-    (min, first-argmin-via-min-iota, one-hot knockout).  Ids are gathered
-    by one-hot sum — branch-free, no take_along_axis inside the kernel."""
-    tq, m = d.shape
-    col = jax.lax.broadcasted_iota(jnp.int32, (tq, m), 1)
-    vals, outs = [], []
-    for _ in range(k):
-        mn = jnp.min(d, axis=1)                                    # (TQ,)
-        amn = jnp.min(jnp.where(d == mn[:, None], col, m), axis=1)
-        hit = col == amn[:, None]
-        vals.append(mn)
-        outs.append(jnp.sum(jnp.where(hit, ids, 0), axis=1).astype(jnp.int32))
-        d = jnp.where(hit, _INF, d)
-    return jnp.stack(vals, axis=1), jnp.stack(outs, axis=1)
 
 
 def _stream_kernel(
@@ -86,28 +72,24 @@ def _stream_kernel(
         run_i[...] = jnp.full(run_i.shape, -1, jnp.int32)
         run_f[...] = jnp.zeros(run_f.shape, jnp.int32)
 
-    # The dot consumes the operands at their STORED dtype (f32, or bf16
-    # under distance_dtype="bf16" — half the candidate-DMA bytes and the
-    # MXU's native low-precision path) while accumulating in f32.  The
-    # norm terms upcast first: bf16→f32 is exact, so every distance is
-    # an exact-f32 function of the (possibly bf16-cast) inputs and the
-    # fp32 path is bit-identical to the pre-bf16 kernel.
+    # Operands arrive at their STORED dtype (f32, or bf16 under
+    # distance_dtype="bf16" — half the candidate-DMA bytes).  bf16→f32
+    # is exact, so every distance is an exact-f32 function of the
+    # (possibly bf16-cast) inputs.
     q_raw = q_ref[...]                                             # (TQ, D)
     c_raw = c_ref[...]                                             # (TC, D)
-    q = q_raw.astype(jnp.float32)
-    c = c_raw.astype(jnp.float32)
-    qc = jax.lax.dot_general(
-        q_raw, c_raw, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                                              # MXU
     if metric == "ip":
-        # Negated inner product: the matmul IS the score — no norm
-        # terms, no max-0 clamp (ip scores are legitimately negative).
-        d = -qc                                                    # (TQ, TC)
+        # Negated inner product on the MXU (bf16 operands take its
+        # native pass): no cancellation, no max-0 clamp (ip scores are
+        # legitimately negative).
+        d = -jax.lax.dot_general(
+            q_raw, c_raw, (((1,), (1,)), ((), ())),
+            precision=f32_precision(q_raw.dtype),
+            preferred_element_type=jnp.float32,
+        )                                                          # (TQ, TC)
     else:
-        qq = jnp.sum(q * q, axis=1, keepdims=True)                 # (TQ, 1)
-        cc = jnp.sum(c * c, axis=1, keepdims=True).T               # (1, TC)
-        d = jnp.maximum(qq + cc - 2.0 * qc, 0.0)                   # (TQ, TC)
+        d = sq_l2_direct(q_raw.astype(jnp.float32),
+                         c_raw.astype(jnp.float32))                # (TQ, TC)
 
     qids = qid_ref[...]                                            # (TQ, 1)
     cids = cid_ref[...]                                            # (1, TC)
@@ -123,7 +105,7 @@ def _stream_kernel(
         alli = jnp.concatenate(
             [run_i[...], jnp.broadcast_to(cids, d.shape)], axis=1
         )
-        vals, ids = _merge_topk(alld, alli, k)
+        vals, ids = topk_min_passes(alld, alli, k)
         run_d[...] = vals
         run_i[...] = ids
 
@@ -204,7 +186,12 @@ def knn_stream_topk_prefetch(
             # is a runtime value, not a grid coordinate.
             pl.BlockSpec((block_c, dim), lambda i, j, blk: (blk[i, j], 0)),
             pl.BlockSpec((block_q, 1), lambda i, j, blk: (i, 0)),
-            pl.BlockSpec((1, block_c), lambda i, j, blk: (i, j)),
+            # Step (i, j)'s ids as a (1, block_c) block of a
+            # (T, nblk, 1, block_c) array: Mosaic needs a block's last two
+            # dims to be (8, 128) multiples or the array's own, which a
+            # (1, block_c) slice of a (T, nblk·block_c) array is not.
+            pl.BlockSpec((None, None, 1, block_c),
+                         lambda i, j, blk: (i, j, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((block_q, k), lambda i, j, blk: (i, 0)),
@@ -225,7 +212,7 @@ def knn_stream_topk_prefetch(
             jax.ShapeDtypeStruct((q_n, k), jnp.int32),
             jax.ShapeDtypeStruct((q_n, 1), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -234,7 +221,7 @@ def knn_stream_topk_prefetch(
         jnp.reshape(eps2, (1, 1)).astype(jnp.float32),
         queries, corpus,
         query_ids.astype(jnp.int32)[:, None],
-        cand_ids.astype(jnp.int32),
+        cand_ids.astype(jnp.int32).reshape(n_tiles, nblk, 1, block_c),
     )
     return outd, outi, outf[:, 0]
 
@@ -299,7 +286,7 @@ def knn_stream_topk_padded(
             pltpu.VMEM((block_q, k), jnp.int32),      # running top-K ids
             pltpu.VMEM((block_q, 1), jnp.int32),      # running found count
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -13,37 +13,13 @@ import logging
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import use_pallas
 from repro.utils import round_up
 from repro.kernels.knn_stream import kernel as _kernel
 from repro.kernels.knn_stream import ref as _ref
+from repro.kernels.knn_topk.ops import warn_oversized_k
 
 _log = logging.getLogger(__name__)
-
-# Process-wide once-flag for the oversized-k fallback notice.  The ref
-# oracle is a silent asymptotic cliff (materialize-then-sort instead of
-# the streaming kernel), so the reroute is worth one loud line — but
-# only one: the fallback fires per jit trace and a k sweep would
-# otherwise spam a line per shape.
-_oversized_k_warned = False
-
-
-def _warn_oversized_k(k: int) -> None:
-    global _oversized_k_warned
-    if not _oversized_k_warned:
-        _oversized_k_warned = True
-        _log.warning(
-            "knn_stream: k=%d exceeds MAX_UNROLLED_K=%d — routing to the "
-            "materialize-then-sort ref oracle (exact, but the streaming "
-            "kernel's memory ceiling no longer applies; further oversized-k "
-            "traces fall back silently)",
-            k, _kernel.MAX_UNROLLED_K,
-        )
-
-
-def _use_pallas(mode: str) -> bool:
-    if mode == "auto":
-        return jax.default_backend() == "tpu"
-    return mode in ("pallas", "interpret")
 
 
 @functools.partial(
@@ -71,9 +47,10 @@ def knn_stream_topk(
     ``knn_topk.ops`` (the unrolled merge network stops paying for
     itself past ``MAX_UNROLLED_K``); the first such reroute per process
     logs a warning so the cliff is visible."""
-    if not _use_pallas(mode) or k > _kernel.MAX_UNROLLED_K:
-        if _use_pallas(mode):
-            _warn_oversized_k(k)
+    if not use_pallas(mode) or k > _kernel.MAX_UNROLLED_K:
+        if use_pallas(mode):
+            warn_oversized_k(_log, "knn_stream", k,
+                             "the materialize-then-sort ref oracle")
         return _ref.knn_stream_topk_ref(
             queries, candidates, query_ids, cand_ids, eps2, k=k, metric=metric
         )
@@ -118,7 +95,7 @@ def knn_stream_topk_prefetch(
     by an explicit gather (the oracle); oversized k raises — callers
     route oversized k through the gathered path instead, where the
     budget-shaped operand the oracle needs already exists."""
-    if not _use_pallas(mode):
+    if not use_pallas(mode):
         return _ref.knn_stream_topk_prefetch_ref(
             queries, corpus, block_table, query_ids, cand_ids, eps2,
             k=k, block_q=block_q, block_c=block_c, metric=metric,

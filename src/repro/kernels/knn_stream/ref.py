@@ -13,6 +13,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import f32_precision
+
 
 @functools.partial(jax.jit, static_argnames=("k", "metric"))
 def knn_stream_topk_ref(
@@ -33,7 +35,7 @@ def knn_stream_topk_ref(
     q = queries.astype(jnp.float32)
     c = candidates.astype(jnp.float32)
     if metric == "ip":
-        d = -(q @ c.T)                                         # (Q, C)
+        d = -jnp.matmul(q, c.T, precision=f32_precision(q.dtype))  # (Q, C)
     else:
         diff = q[:, None, :] - c[None, :, :]
         d = jnp.sum(diff * diff, axis=-1)                      # (Q, C)

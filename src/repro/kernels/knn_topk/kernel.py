@@ -4,14 +4,16 @@ This is the beyond-paper optimization that replaces the paper's batching
 scheme (§IV-B) on TPU (DESIGN.md §2.3): instead of materializing an
 unbounded range-query result set in HBM (which forced the paper into a
 result-size estimator + n_b staged batches), each (query tile × candidate
-tile) step computes the distance tile on the MXU and immediately reduces it
+tile) step computes the distance tile in VMEM (``sq_l2_direct``; the MXU
+for inner products) and immediately reduces it
 to the tile's K smallest (distance, index) pairs in VMEM.  HBM traffic
 drops from O(Q·C) to O(Q·(C/TC)·K), and a log-depth top-K reduction in
 ops.py finishes the job — memory is statically bounded, no failure/restart.
 
 The K-smallest extraction is K passes of (min, first-argmin-via-min-iota,
 one-hot mask) — branch-free, VPU-friendly, no unsupported sort/topk
-primitives inside the kernel.
+primitives inside the kernel.  ``topk_min_passes`` is shared with the
+streaming kernel (``knn_stream``).
 """
 from __future__ import annotations
 
@@ -23,12 +25,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro.kernels import f32_precision
 
 _INF = np.float32(np.inf)
 
-# ``_tile_topk`` unrolls K serial min-passes into straight-line kernel code.
+# ``topk_min_passes`` unrolls K serial min-passes into straight-line kernel
+# code.
 # Past this ceiling the unrolled loop stops being a win: compile time and
 # kernel size grow linearly while the per-pass VPU reductions dominate the
 # MXU matmul they amortize.  ``ops.knn_topk`` falls back to the jnp ref
@@ -38,36 +40,53 @@ _INF = np.float32(np.inf)
 MAX_UNROLLED_K = 32
 
 
-def _tile_topk(d: jnp.ndarray, k: int):
-    """K-smallest per row of d (TQ, TC) -> (vals (TQ, k), cols (TQ, k))."""
-    tq, tc = d.shape
-    col = jax.lax.broadcasted_iota(jnp.int32, (tq, tc), 1)
-    vals, cols = [], []
+def topk_min_passes(d: jnp.ndarray, ids: jnp.ndarray, k: int):
+    """K smallest per row of ``d`` (TQ, M) with their ``ids`` (TQ, M):
+    K passes of (min, first-argmin-via-min-iota, one-hot knockout).  Ids
+    are gathered by one-hot sum — branch-free, and Mosaic lowers it
+    (it has no lowering for ``take_along_axis`` inside a kernel)."""
+    tq, m = d.shape
+    col = jax.lax.broadcasted_iota(jnp.int32, (tq, m), 1)
+    vals, outs = [], []
     for _ in range(k):
-        mn = jnp.min(d, axis=1)                                     # (TQ,)
-        is_mn = d == mn[:, None]
-        amn = jnp.min(jnp.where(is_mn, col, tc), axis=1)            # first argmin
+        mn = jnp.min(d, axis=1)                                    # (TQ,)
+        amn = jnp.min(jnp.where(d == mn[:, None], col, m), axis=1)
+        hit = col == amn[:, None]
         vals.append(mn)
-        cols.append(amn)
-        d = jnp.where(col == amn[:, None], _INF, d)
-    return jnp.stack(vals, axis=1), jnp.stack(cols, axis=1).astype(jnp.int32)
+        outs.append(jnp.sum(jnp.where(hit, ids, 0), axis=1).astype(jnp.int32))
+        d = jnp.where(hit, _INF, d)
+    return jnp.stack(vals, axis=1), jnp.stack(outs, axis=1)
+
+
+def sq_l2_direct(q: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
+    """(TQ, D) × (TC, D) f32 -> (TQ, TC) squared L2 from direct
+    differences, one VPU pass per dimension.  The MXU expansion
+    ‖q‖² + ‖c‖² − 2·q·c cancels away f32 digits: its absolute error is
+    ~1e-7·‖x‖², which on unit-scale data is ~1e-3 relative on a close
+    neighbor's distance — too coarse for an exact index.  Differences
+    keep every distance within f32 rounding of the true one."""
+    ct = c.T                                                        # (D, TC)
+    d = jnp.zeros((q.shape[0], ct.shape[1]), jnp.float32)
+    for j in range(q.shape[1]):
+        diff = q[:, j:j + 1] - ct[j:j + 1, :]
+        d = d + diff * diff
+    return d
 
 
 def _knn_topk_kernel(q_ref, c_ref, qid_ref, cid_ref, outd_ref, outi_ref,
                      *, k: int, metric: str):
     q = q_ref[...].astype(jnp.float32)                              # (TQ, D)
     c = c_ref[...].astype(jnp.float32)                              # (TC, D)
-    qc = jax.lax.dot_general(
-        q, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
     if metric == "ip":
-        # Negated inner product: same MXU matmul, no norm terms, and no
+        # Negated inner product on the MXU: no cancellation, and no
         # max-0 clamp — ip scores are legitimately negative.
-        d = -qc                                                     # (TQ, TC)
+        d = -jax.lax.dot_general(
+            q, c, (((1,), (1,)), ((), ())),
+            precision=f32_precision(q.dtype),
+            preferred_element_type=jnp.float32,
+        )                                                           # (TQ, TC)
     else:
-        qq = jnp.sum(q * q, axis=1, keepdims=True)
-        cc = jnp.sum(c * c, axis=1, keepdims=True).T
-        d = jnp.maximum(qq + cc - 2.0 * qc, 0.0)                    # (TQ, TC)
+        d = sq_l2_direct(q, c)                                      # (TQ, TC)
 
     qids = qid_ref[...]                                             # (TQ, 1) i32
     cids = cid_ref[...]                                             # (1, TC) i32
@@ -75,12 +94,9 @@ def _knn_topk_kernel(q_ref, c_ref, qid_ref, cid_ref, outd_ref, outi_ref,
     invalid = (cids < 0) | (qids == cids)
     d = jnp.where(invalid, _INF, d)
 
-    vals, cols = _tile_topk(d, k)                                   # (TQ, k)
-    gathered = jnp.take_along_axis(
-        jnp.broadcast_to(cids, d.shape), cols, axis=1
-    )
+    vals, ids = topk_min_passes(d, jnp.broadcast_to(cids, d.shape), k)
     outd_ref[0] = vals
-    outi_ref[0] = jnp.where(jnp.isinf(vals), -1, gathered)
+    outi_ref[0] = jnp.where(jnp.isinf(vals), -1, ids)
 
 
 @functools.partial(
@@ -133,7 +149,7 @@ def knn_tile_topk(
             jax.ShapeDtypeStruct((n_c, q_n, k), jnp.float32),
             jax.ShapeDtypeStruct((n_c, q_n, k), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -3,19 +3,37 @@ the log-depth merge that finishes the per-tile partial top-Ks."""
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import use_pallas
 from repro.utils import round_up
 from repro.kernels.knn_topk import kernel as _kernel
 from repro.kernels.knn_topk import ref as _ref
 
+_log = logging.getLogger(__name__)
 
-def _use_pallas(mode: str) -> bool:
-    if mode == "auto":
-        return jax.default_backend() == "tpu"
-    return mode in ("pallas", "interpret")
+# Engines whose oversized-k reroute has been logged in this process.
+_oversized_k_warned: set = set()
+
+
+def warn_oversized_k(log: logging.Logger, engine: str, k: int,
+                     fallback: str) -> None:
+    """Log, once per ``engine`` per process, that ``k`` above
+    ``MAX_UNROLLED_K`` rerouted a kernel call to its jnp ``fallback``.
+    The reroute is an exact but silent cliff, so it is worth one loud
+    line — and only one: it fires per jit trace, and a k sweep would
+    otherwise log a line per shape."""
+    if engine in _oversized_k_warned:
+        return
+    _oversized_k_warned.add(engine)
+    log.warning(
+        "%s: k=%d exceeds MAX_UNROLLED_K=%d — routing to %s (exact, but "
+        "not the Pallas kernel; further oversized-k traces fall back "
+        "silently)", engine, k, _kernel.MAX_UNROLLED_K, fallback,
+    )
 
 
 @functools.partial(
@@ -40,7 +58,10 @@ def knn_topk(
     candidates exist)."""
     # Oversized K: the kernel's unrolled min-pass extraction stops paying
     # for itself (see kernel.MAX_UNROLLED_K) — take the ref merge path.
-    if not _use_pallas(mode) or k > _kernel.MAX_UNROLLED_K:
+    if not use_pallas(mode) or k > _kernel.MAX_UNROLLED_K:
+        if use_pallas(mode):
+            warn_oversized_k(_log, "knn_topk", k,
+                             "the full-tile jnp reference merge")
         return _ref.knn_topk_ref(queries, candidates, query_ids, cand_ids,
                                  k=k, metric=metric)
 

@@ -6,6 +6,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import f32_precision
+
 
 @functools.partial(jax.jit, static_argnames=("k", "metric"))
 def knn_topk_ref(
@@ -24,7 +26,7 @@ def knn_topk_ref(
     q = queries.astype(jnp.float32)
     c = candidates.astype(jnp.float32)
     if metric == "ip":
-        d = -(q @ c.T)
+        d = -jnp.matmul(q, c.T, precision=f32_precision(q.dtype))
     else:
         diff = q[:, None, :] - c[None, :, :]
         d = jnp.sum(diff * diff, axis=-1)

@@ -1,11 +1,14 @@
 """Pallas TPU kernel: tiled pairwise squared-L2 distances (the paper's
-"filtering" hot spot, recast for the MXU — DESIGN.md §2.1).
+"filtering" hot spot).
 
-``dist²(q, c) = ‖q‖² + ‖c‖² − 2·q·cᵀ`` so the inner loop of the join is a
-(TQ×TD)·(TD×TC) matmul on the systolic array plus rank-1 row/col updates on
-the VPU.  The grid is (query tiles × candidate tiles × d-chunks); the
-d-chunk axis accumulates into the output block, so the full (Q, C) matrix
-is built tile-by-tile with VMEM-resident operands.
+Each (TQ×TD)·(TC×TD) step adds the chunk's squared differences to the
+output block, one VPU pass per dimension (``knn_topk.sq_l2_direct``):
+the MXU expansion ``‖q‖² + ‖c‖² − 2·q·cᵀ`` cancels away f32 digits and
+is too coarse for an exact index (DESIGN.md §11.2).  The inner-product
+metric stays one MXU matmul per chunk.  The grid is (query tiles ×
+candidate tiles × d-chunks); the d-chunk axis accumulates into the
+output block, so the full (Q, C) matrix is built tile-by-tile with
+VMEM-resident operands.
 
 TSTATIC/TDYNAMIC (paper §V-G) map to the (block_q, block_c) tile shape —
 ``block_c`` plays "threads per query point" (candidates processed per step
@@ -25,8 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro.kernels import f32_precision
+from repro.kernels.knn_topk.kernel import sq_l2_direct
 
 
 def _pairwise_kernel(*refs, shortc_eps2: float | None, shortc_dynamic: bool,
@@ -45,17 +48,16 @@ def _pairwise_kernel(*refs, shortc_eps2: float | None, shortc_dynamic: bool,
     def _accumulate():
         q = q_ref[...].astype(jnp.float32)                 # (TQ, TD)
         c = c_ref[...].astype(jnp.float32)                 # (TC, TD)
-        qc = jax.lax.dot_general(
-            q, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )                                                  # (TQ, TC) on the MXU
         if metric == "ip":
-            # Same MXU matmul, no norm terms: the d-chunk axis
-            # accumulates the negated inner product directly.
-            out_ref[...] += -qc
+            # The d-chunk axis accumulates the negated inner product,
+            # one MXU matmul per chunk.
+            out_ref[...] += -jax.lax.dot_general(
+                q, c, (((1,), (1,)), ((), ())),
+                precision=f32_precision(q.dtype),
+                preferred_element_type=jnp.float32,
+            )                                              # (TQ, TC)
         else:
-            qq = jnp.sum(q * q, axis=1, keepdims=True)     # (TQ, 1)
-            cc = jnp.sum(c * c, axis=1, keepdims=True).T   # (1, TC)
-            out_ref[...] += qq + cc - 2.0 * qc
+            out_ref[...] += sq_l2_direct(q, c)             # (TQ, TC)
 
     if shortc_eps2 is None and not shortc_dynamic:
         _accumulate()
@@ -154,7 +156,7 @@ def _pallas_pairwise(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_q, block_c), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((q_n, c_n), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,11 +1,5 @@
-"""jit'd public wrapper for the pairwise-L2 kernel: padding + dispatch.
-
-Dispatch policy (shared by all kernel packages):
-  * ``mode="auto"``   — Pallas (compiled) on TPU, jnp oracle elsewhere.
-  * ``mode="pallas"`` — Pallas compiled (TPU only).
-  * ``mode="interpret"`` — Pallas in interpret mode (CPU validation path).
-  * ``mode="ref"``    — jnp oracle.
-"""
+"""jit'd public wrapper for the pairwise-L2 kernel: padding + dispatch
+(``repro.kernels.use_pallas`` policy)."""
 from __future__ import annotations
 
 import functools
@@ -13,15 +7,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import use_pallas
 from repro.utils import round_up
 from repro.kernels.pairwise_l2 import kernel as _kernel
 from repro.kernels.pairwise_l2 import ref as _ref
-
-
-def _use_pallas(mode: str) -> bool:
-    if mode == "auto":
-        return jax.default_backend() == "tpu"
-    return mode in ("pallas", "interpret")
 
 
 def pairwise_sq_l2(
@@ -71,14 +60,19 @@ def pairwise_sq_l2(
 
 
 def _pad_operands(queries, candidates, block_q, block_c, block_d):
+    """Zero-pad to tile multiples.  Rows of at most ``block_d`` features
+    stay one unpadded chunk (a block equal to the array's last dim needs
+    no lane padding, and the L2 kernel loops over every column).
+    Returns (q, c, the feature chunk)."""
     q_n, d = queries.shape
     c_n, _ = candidates.shape
+    block_d = min(block_d, max(d, 1))
     qp = round_up(max(q_n, 1), block_q)
     cp = round_up(max(c_n, 1), block_c)
     dp = round_up(max(d, 1), block_d)
     q = jnp.zeros((qp, dp), queries.dtype).at[:q_n, :d].set(queries)
     c = jnp.zeros((cp, dp), candidates.dtype).at[:c_n, :d].set(candidates)
-    return q, c
+    return q, c, block_d
 
 
 @functools.partial(
@@ -92,11 +86,12 @@ def _pairwise_static(
 ):
     q_n, _ = queries.shape
     c_n, _ = candidates.shape
-    if not _use_pallas(mode):
+    if not use_pallas(mode):
         if metric == "ip":
             return _ref.pairwise_neg_ip_ref(queries, candidates)
         return _ref.pairwise_sq_l2_ref(queries, candidates)
-    q, c = _pad_operands(queries, candidates, block_q, block_c, block_d)
+    q, c, block_d = _pad_operands(queries, candidates, block_q, block_c,
+                                  block_d)
     out = _kernel.pairwise_sq_l2(
         q, c,
         block_q=block_q, block_c=block_c, block_d=block_d,
@@ -114,11 +109,12 @@ def _pairwise_dynamic(
 ):
     q_n, _ = queries.shape
     c_n, _ = candidates.shape
-    if not _use_pallas(mode):
+    if not use_pallas(mode):
         # The ref oracle computes exact distances; SHORTC only ever clamps
         # values already above the cutoff, so exact is a valid refinement.
         return _ref.pairwise_sq_l2_ref(queries, candidates)
-    q, c = _pad_operands(queries, candidates, block_q, block_c, block_d)
+    q, c, block_d = _pad_operands(queries, candidates, block_q, block_c,
+                                  block_d)
     out = _kernel.pairwise_sq_l2_dyn_shortc(
         q, c, shortc_eps2,
         block_q=block_q, block_c=block_c, block_d=block_d,

@@ -4,6 +4,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import f32_precision
+
 
 @jax.jit
 def pairwise_sq_l2_ref(queries: jnp.ndarray, candidates: jnp.ndarray) -> jnp.ndarray:
@@ -21,14 +23,4 @@ def pairwise_neg_ip_ref(queries: jnp.ndarray, candidates: jnp.ndarray) -> jnp.nd
     (ascending = best-first, matching the L2 score convention)."""
     q = queries.astype(jnp.float32)
     c = candidates.astype(jnp.float32)
-    return -(q @ c.T)
-
-
-@jax.jit
-def pairwise_sq_l2_matmul_ref(queries: jnp.ndarray, candidates: jnp.ndarray) -> jnp.ndarray:
-    """Matmul-form oracle — bit-comparable to the kernel's arithmetic."""
-    q = queries.astype(jnp.float32)
-    c = candidates.astype(jnp.float32)
-    qq = jnp.sum(q * q, axis=1, keepdims=True)
-    cc = jnp.sum(c * c, axis=1, keepdims=True).T
-    return qq + cc - 2.0 * (q @ c.T)
+    return -jnp.matmul(q, c.T, precision=f32_precision(q.dtype))

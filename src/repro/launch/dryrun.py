@@ -8,17 +8,19 @@ collective-byte parsing feed EXPERIMENTS.md §Dry-run and §Roofline.
 
 Usage::
 
-    PYTHONPATH=src python -m repro.launch.dryrun --arch olmo_1b \
-        --shape train_4k --mesh both
-    PYTHONPATH=src python -m repro.launch.dryrun --all            # 40 cells
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.dryrun \
+        --arch olmo_1b --shape train_4k --mesh both
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.dryrun --all
 """
-# The VERY FIRST lines, before ANY other import (jax locks the device
-# count at first init):
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# Before anything initializes a JAX backend (the device count locks
+# then):
+from repro.utils import force_host_devices
+
+force_host_devices(512)
 
 import argparse      # noqa: E402
 import json          # noqa: E402
+import os            # noqa: E402
 import time          # noqa: E402
 import traceback     # noqa: E402
 

@@ -15,22 +15,17 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-try:  # jax ≥ 0.5 has explicit axis types; older versions are Auto-only.
-    from jax.sharding import AxisType
 
-    def _axis_kw(n_axes: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n_axes}
-except ImportError:
-    def _axis_kw(n_axes: int) -> dict:
-        return {}
+def _auto(n_axes: int) -> tuple:
+    return (AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kw(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh(model: int = 1) -> Mesh:
@@ -38,7 +33,7 @@ def make_host_mesh(model: int = 1) -> Mesh:
     used by tests (8 host devices) and CPU examples (1 device)."""
     n = len(jax.devices())
     data = n // model
-    return jax.make_mesh((data, model), ("data", "model"), **_axis_kw(2))
+    return jax.make_mesh((data, model), ("data", "model"), axis_types=_auto(2))
 
 
 def make_serving_mesh(n_shards: int | None = None, axis: str = "shard",
@@ -67,8 +62,8 @@ def make_serving_mesh(n_shards: int | None = None, axis: str = "shard",
             "before the first jax import to fake more on CPU)"
         )
     if r == 1:
-        return jax.make_mesh((n,), (axis,), **_axis_kw(1))
-    return jax.make_mesh((r, n), ("replica", axis), **_axis_kw(2))
+        return jax.make_mesh((n,), (axis,), axis_types=_auto(1))
+    return jax.make_mesh((r, n), ("replica", axis), axis_types=_auto(2))
 
 
 def mesh_chip_count(mesh: Mesh) -> int:

@@ -115,9 +115,9 @@ def sharded_lookup(mesh: Mesh, axis: str, *, k: int):
     ring = [(i, (i + 1) % n_shards) for i in range(n_shards)]
 
     def local(q, keys, vals):
-        run_d = utils.pcast(
+        run_d = jax.lax.pcast(
             jnp.full((q.shape[0], k), jnp.inf, jnp.float32), axis, to="varying")
-        run_v = utils.pcast(
+        run_v = jax.lax.pcast(
             jnp.full((q.shape[0], k), -1, jnp.int32), axis, to="varying")
 
         def step(_, carry):
@@ -136,7 +136,7 @@ def sharded_lookup(mesh: Mesh, axis: str, *, k: int):
             0, n_shards, step, (run_d, run_v, keys, vals))
         return rd, rv
 
-    shard_fn = utils.shard_map(
+    shard_fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(axis), P(axis)),
         out_specs=(P(), P()),

@@ -55,6 +55,7 @@ from repro.core import grid as grid_lib
 from repro.core import queue as queue_lib
 from repro.core import sparse_knn as sparse_lib
 from repro.core import splitter as split_lib
+from repro.kernels import f32_precision
 from repro.retrieval import metrics as met_lib
 from repro.retrieval import projection as proj_lib
 from repro.runtime import mutation as mut_lib
@@ -71,12 +72,20 @@ def clear_engine_cache() -> None:
 
 def _engine_key(kind: str, args: tuple, kwargs: dict) -> tuple:
     """Cache key: pytree structure (static fields ride in the treedef),
-    leaf avals (shape, dtype), and the static kwargs."""
+    leaf avals (shape, dtype), the devices committed arguments live on
+    (an executable runs on the devices it was compiled for, and each
+    shard of a ``ShardedKNNIndex`` lives on its own), and the static
+    kwargs."""
     leaves, treedef = jax.tree_util.tree_flatten(args)
     avals = tuple(
         (tuple(np.shape(leaf)), str(jnp.result_type(leaf))) for leaf in leaves
     )
-    return (kind, treedef, avals, tuple(sorted(kwargs.items())))
+    devices = tuple(sorted({
+        d.id for leaf in leaves
+        if isinstance(leaf, jax.Array) and leaf.committed
+        for d in leaf.sharding.device_set
+    }))
+    return (kind, treedef, avals, devices, tuple(sorted(kwargs.items())))
 
 
 def run_engine(owner, kind: str, jitted, args: tuple, kwargs: dict):
@@ -88,7 +97,9 @@ def run_engine(owner, kind: str, jitted, args: tuple, kwargs: dict):
     key = _engine_key(kind, args, kwargs)
     ex = _ENGINE_CACHE.get(key)
     if ex is None:
+        t0 = time.perf_counter()
         ex = jitted.lower(*args, **kwargs).compile()
+        queue_lib.charge_compile(time.perf_counter() - t0)
         _ENGINE_CACHE[key] = ex
         owner.compile_counts[kind] = owner.compile_counts.get(kind, 0) + 1
     owner.executables[kind] = ex
@@ -225,6 +236,7 @@ def _rescore_engine(points_full, queries_f, cand_ids, excl, *, k, metric):
     if metric == "ip":
         d = -jax.lax.dot_general(
             queries_f, cand_pts, (((1,), (2,)), ((0,), (0,))),
+            precision=f32_precision(queries_f.dtype),
             preferred_element_type=jnp.float32,
         )                                              # (Qp, kc)
     else:
@@ -657,7 +669,7 @@ class KNNIndex:
         eps_arg = jnp.float32(
             gen.eps if eps_scale is None else gen.eps * eps_scale)
 
-        def dense_fn(ids: np.ndarray):
+        def dense_fn(ids: np.ndarray, compile_only: bool = False):
             qp = hybrid_lib._pad_ids(ids, cfg.query_block)
             args = (gen.grid, gen.points_r, qp, eps_arg)
             if queries_rp is not None:
@@ -669,6 +681,8 @@ class KNNIndex:
                 distance_dtype=cfg.distance_dtype,
             )
             ex = self._engine("dense", dense_lib.dense_join_jit, args, kwargs)
+            if compile_only:
+                return None
             t0 = time.perf_counter()
             res = jax.block_until_ready(ex(*args))
             dt = time.perf_counter() - t0
@@ -686,7 +700,7 @@ class KNNIndex:
                    exclude_self: bool):
         cfg = self.config
 
-        def sparse_fn(ids: np.ndarray) -> queue_lib.AsyncEngineCall:
+        def sparse_fn(ids: np.ndarray, compile_only: bool = False):
             qp = hybrid_lib._pad_ids(ids, cfg.query_block)
             args = (gen.pyramid, gen.points_r, qp)
             if queries_rp is not None:
@@ -699,6 +713,8 @@ class KNNIndex:
                 distance_dtype=cfg.distance_dtype,
             )
             ex = self._engine("sparse", sparse_lib.sparse_knn_jit, args, kwargs)
+            if compile_only:
+                return None
             raw = ex(*args)     # async dispatch: returns un-blocked arrays
             n = len(ids)
 
@@ -884,14 +900,30 @@ class KNNIndex:
         merge-time folding, so folds compare like with like)."""
         cfg = self.config
         rho_floor = cfg.rho if rho is None else rho
-        return queue_lib.run_work_queue(
+        dense_fn = self._dense_fn(gen, kq, queries_rp, exclude_self)
+        sparse_fn = self._sparse_fn(gen, kq, queries_rp, exclude_self)
+        # The first round's timings seed the online ρ rebalance, so they
+        # must not depend on the engine cache: compile that round's
+        # engines before the queue dispatches any.  (Were the dense
+        # engine compiled while the first sparse round runs, the device
+        # would finish that round unobserved, a cold query would demote
+        # differently from a warm one, and the warm one would compile
+        # the buckets its demotions land in.)
+        t0 = time.perf_counter()
+        if len(sparse_ids):
+            sparse_fn(sparse_ids, compile_only=True)
+        first = -(-len(dense_ids) // cfg.n_batches)
+        if first:
+            dense_fn(dense_ids[:first], compile_only=True)
+        t_prepare = time.perf_counter() - t0
+        *answers, report = queue_lib.run_work_queue(
             npts=n_q,
             k=kq,
             dense_ids=dense_ids,
             sparse_ids=sparse_ids,
             home_counts=home_counts,
-            dense_fn=self._dense_fn(gen, kq, queries_rp, exclude_self),
-            sparse_fn=self._sparse_fn(gen, kq, queries_rp, exclude_self),
+            dense_fn=dense_fn,
+            sparse_fn=sparse_fn,
             brute_fn=self._brute_fn(gen, kq, queries_rp, exclude_self),
             n_batches=cfg.n_batches,
             online_rebalance=cfg.online_rebalance,
@@ -899,6 +931,8 @@ class KNNIndex:
             min_sparse=int(math.ceil(rho_floor * n_q)),
             demote_quantum=cfg.query_block,
         )
+        report.t_wall += t_prepare      # the query's wall holds its compiles
+        return (*answers, report)
 
     def _stats(self, gen: _Generation, n_dense: int, n_sparse: int,
                threshold: float, report, compiles_before: int,

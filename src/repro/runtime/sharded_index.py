@@ -13,17 +13,18 @@ module makes *placement* a layer instead of a fork:
     shard's local grid stays dense (Gowanlock's grid-partitioned
     self-join, applied to serving).  Shard-local grid+pyramid state is
     built in one ``shard_map`` program (``distributed
-    .build_shard_indices`` via the ``repro.utils`` jax-0.4.x shims);
-    each shard is then a plain ``KNNIndex`` over its sub-cloud.
+    .build_shard_indices``); each shard is then a plain ``KNNIndex``
+    over its sub-cloud, its state committed to its own device.
 
   * ``index.query(queries, k, exclude_self)`` runs the EXISTING hybrid
     dense/sparse/brute pipeline per shard — AOT engine cache, pow2
-    query buckets, and all four backends unchanged; because every shard
-    has the same static shapes, P shards share ONE set of compiled
-    engines — and merges the P shard-local top-K candidate sets with a
-    collective merge (``distributed.collective_topk_merge``: all-gather
-    + ``knn_topk.merge_running_topk`` fold, or the ``ppermute``
-    tree-merge for large pow2 P).  The merge executable lives in the
+    query buckets, and all four backends unchanged; every shard has the
+    same static shapes, so each device compiles one set of engines and
+    no shard recompiles per batch — and merges the P shard-local top-K
+    candidate sets with a collective merge
+    (``distributed.collective_topk_merge``: all-gather +
+    ``knn_topk.merge_running_topk`` fold, or the ``ppermute`` tree-merge
+    for large pow2 P).  The merge executable lives in the
     same AOT engine cache under kind ``"merge"``, so the zero-compile
     steady-state guarantee covers the collective step too.
 
@@ -46,12 +47,13 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import repro.core.hybrid as hybrid_lib
 from repro.core import dense_join as dense_lib
 from repro.core import distributed as dist_lib
 from repro.core import grid as grid_lib
+from repro.core import queue as queue_lib
 from repro.core import splitter as split_lib
 from repro.retrieval import metrics as met_lib
 from repro.runtime import mutation as mut_lib
@@ -68,6 +70,19 @@ from repro.utils import cdiv, pow2_bucket
 #: Mesh axis name reserved for replica groups (launch.make_serving_mesh):
 #: index state is replicated along it, so it is never a shard axis.
 REPLICA_AXIS = "replica"
+
+
+def _block_devices(stacked: jax.Array, n_blocks: int) -> List:
+    """The device holding each leading-axis block of a ``shard_map``
+    output — replica 0's copy where the mesh replicates shard state."""
+    out = [None] * n_blocks
+    index_map = stacked.sharding.devices_indices_map(stacked.shape)
+    for dev, idx in sorted(index_map.items(), key=lambda kv: kv[0].id):
+        lo, hi, _ = idx[0].indices(n_blocks)
+        for p in range(lo, hi):
+            if out[p] is None:
+                out[p] = dev
+    return out
 
 
 def _resolve_axes(mesh: Mesh, mesh_axis) -> Tuple[str, ...]:
@@ -245,7 +260,7 @@ class ShardedKNNIndex:
                 points_r, dim_perm = pts, None
 
             # (2) ε selection — once, globally: one grid geometry class,
-            # so P equal-shape shards share one set of compiled engines.
+            # so P equal-shape shards compile the same engine shapes.
             eps, eps_beta, t_select = select_epsilon(
                 points_r, cfg, epsilon, npts)
 
@@ -277,7 +292,8 @@ class ShardedKNNIndex:
         assert off == npts
 
         # (4) shard-local grid + pyramid, one shard_map program.
-        pts_stacked = jnp.asarray(np.asarray(points_r)[gids])  # (P, s, n)
+        pts_stacked = jax.device_put(                          # (P, s, n)
+            np.asarray(points_r)[gids], NamedSharding(mesh, P(axes)))
         grids, pyramids = dist_lib.build_shard_indices(
             mesh, axes, pts_stacked, eps, m,
             n_levels=cfg.n_levels, level_scale=cfg.level_scale,
@@ -293,12 +309,19 @@ class ShardedKNNIndex:
         # (5) each shard is a plain KNNIndex over its sub-cloud: REORDER
         # already applied, ε pinned, grid/pyramid prebuilt, counters and
         # executables shared so P shards look like one serving engine.
+        # Each shard's state is committed to the device that built it, so
+        # its engines compile for and run on that chip (the engine-cache
+        # key carries the devices).
         shard_cfg = dataclasses.replace(cfg, reorder=False)
+        devices = _block_devices(grids.unique_cells, n_shards)
         shards = []
         for p in range(n_shards):
-            g = jax.tree_util.tree_map(lambda x, p=p: x[p], grids)
-            pyr = jax.tree_util.tree_map(lambda x, p=p: x[p], pyramids)
-            spts = pts_stacked[p]
+            def local(x, p=p):
+                return jax.device_put(x[p], devices[p])
+
+            g = jax.tree_util.tree_map(local, grids)
+            pyr = jax.tree_util.tree_map(local, pyramids)
+            spts = local(pts_stacked)
             shards.append(KNNIndex(
                 shard_cfg, backend=bk,
                 points_ref=spts, points_r=spts, dim_perm=None,
@@ -483,7 +506,9 @@ class ShardedKNNIndex:
         key = _engine_key("merge", args, kwargs)
         ex = _ENGINE_CACHE.get(key)
         if ex is None:
+            t0 = time.perf_counter()
             ex = jitted.lower(*args).compile()
+            queue_lib.charge_compile(time.perf_counter() - t0)
             _ENGINE_CACHE[key] = ex
             self.compile_counts["merge"] += 1
         self.executables["merge"] = ex
@@ -769,10 +794,10 @@ class ShardedKNNIndex:
                      shard_net_cells=None,
                      serve_shards: Optional[Tuple[int, ...]] = None):
         """Per-shard hybrid serves + the collective top-K merge: shard
-        p answers k_eff candidates over its sub-cloud (equal shapes ⇒
-        shard 0 compiles, shards 1..P−1 ride the same engine-cache
-        entries), local ids map to global, and the collective reduces
-        the P blocks to k_out over the query-shape bucket (same pow2
+        p answers k_eff candidates over its sub-cloud on its own device
+        (equal shapes ⇒ one engine set per device), local ids map to
+        global, and the collective reduces the P blocks to k_out over
+        the query-shape bucket (same pow2
         rounding as the per-shard engines, so batch-size sweeps share
         merge executables too).  Returns the merged (qb, k_out) block
         (post-√ distances), per-shard sources/stats, the merge time,
@@ -842,7 +867,10 @@ class ShardedKNNIndex:
             serve["n_hedge_wins"] += int(out.hedge_won)
             serve["n_subquery_retries"] += out.retries
             serve["n_subquery_failures"] += out.failures
-            lane_times.update(out.times)
+            # A sub-query that compiled engines timed the compiler, not
+            # the replica: keep it out of the straggler detector.
+            if not (out.served and out.result.stats.n_engine_compiles):
+                lane_times.update(out.times)
             if not out.served:
                 serve["shards_lost"].append(p)
                 continue

@@ -23,9 +23,20 @@ from typing import Any, Dict, Optional, Sequence, Tuple, Union
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 AxisEntry = Union[None, str, Tuple[str, ...]]
+
+
+def auto_axes_mesh(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis of type Auto.  The model code places
+    parameters and activations through GSPMD constraints
+    (``ShardingCtx.constrain``), which only name Auto axes; ``jax.make_mesh``
+    makes Explicit axes by default, so a caller's mesh is normalized here."""
+    if mesh.are_all_axes_auto:
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def data_axis_names(mesh: Mesh) -> Tuple[str, ...]:
@@ -150,6 +161,7 @@ class ShardingCtx:
                  seq_shard: bool = True) -> "ShardingCtx":
         if mesh is None:
             return cls(None, {})
+        mesh = auto_axes_mesh(mesh)
         return cls(mesh, logical_rules(mesh, fsdp=fsdp, seq_shard=seq_shard))
 
     def spec(self, axes: Sequence[Optional[str]], shape: Sequence[int]) -> P:

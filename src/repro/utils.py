@@ -2,6 +2,8 @@
 from __future__ import annotations
 
 import dataclasses
+import os
+import pathlib
 import time
 from typing import Any, Callable, Iterable, Sequence
 
@@ -11,6 +13,37 @@ import numpy as np
 
 INT32_SENTINEL = np.int32(2**31 - 1)  # padding value for sorted id arrays
 FLOAT_INF = jnp.inf
+
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[2]
+
+
+def init_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already
+    honours it and nothing changes; otherwise the cache goes to the
+    fixed ``<repo>/.jax_cache`` — a fixed path, since the path is part
+    of what a cache hit matches.  Entry points (``chip_smoke.py``,
+    ``benchmarks/run.py``, ``examples/``) call this first; tests do
+    not."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(REPO_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def force_host_devices(n: int) -> None:
+    """Give XLA's CPU backend ``n`` virtual devices (the dry-run tools'
+    fake production mesh).  Appends to ``XLA_FLAGS``, keeps a device
+    count already there, and acts only when ``JAX_PLATFORMS`` is
+    ``cpu``.  Call it before JAX initializes a backend."""
+    if os.environ.get("JAX_PLATFORMS", "") != "cpu":
+        return
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "--xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count={n}".strip())
 
 
 def pytree_dataclass(cls):
@@ -38,28 +71,6 @@ def pytree_dataclass(cls):
 
 def static_field(**kw):
     return dataclasses.field(metadata={"static": True}, **kw)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, **kw):
-    """``jax.shard_map`` across jax versions: newer releases expose it at
-    the top level with ``check_vma``; older ones keep it under
-    ``jax.experimental.shard_map`` with the ``check_rep`` spelling."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
-def pcast(x, axes, *, to="varying"):
-    """``jax.lax.pcast`` when available (newer jax tracks varying-axis
-    types inside shard_map); identity on older versions, whose
-    replication checker does not require the explicit cast."""
-    fn = getattr(jax.lax, "pcast", None)
-    if fn is None:
-        return x
-    return fn(x, axes, to=to)
 
 
 def cdiv(a: int, b: int) -> int:

@@ -134,11 +134,10 @@ def test_compressed_grad_mean_over_data_axis():
         def local(g, res):
             return compressed_grad_mean({"w": g[0]}, {"w": res[0]}, ("data",))
 
-        from repro.utils import shard_map
-        fn = jax.jit(shard_map(local, mesh=mesh,
-                               in_specs=(P("data"), P("data")),
-                               out_specs=(P(), P("data")),
-                               check_vma=False))
+        fn = jax.jit(jax.shard_map(local, mesh=mesh,
+                                   in_specs=(P("data"), P("data")),
+                                   out_specs=(P(), P("data")),
+                                   check_vma=False))
         mean, new_res = fn(g_global, jnp.zeros((8, 64)))
         want = np.asarray(g_global).mean(axis=0)
         got = np.asarray(mean["w"])

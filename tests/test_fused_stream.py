@@ -23,6 +23,8 @@ from repro.core import sparse_knn as sparse_lib
 from repro.kernels.knn_stream import kernel as stream_kernel
 from repro.kernels.knn_stream import ops as stream_ops
 from repro.kernels.knn_stream import ref as stream_ref
+from repro.kernels.knn_topk import kernel as topk_kernel
+from repro.kernels.knn_topk import ops as topk_ops
 from repro.runtime import JoinSession
 
 
@@ -146,8 +148,8 @@ def test_stream_kernel_max_unrolled_k_boundary():
 def test_oversized_k_fallback_logs_once(monkeypatch, caplog):
     """The oversized-k reroute to the ref oracle logs exactly one
     warning per process — visible the first time, silent on every later
-    trace (ISSUE 10 satellite: the cliff used to be silent)."""
-    monkeypatch.setattr(stream_ops, "_oversized_k_warned", False)
+    trace (the cliff used to be silent)."""
+    monkeypatch.setattr(topk_ops, "_oversized_k_warned", set())
     r = np.random.default_rng(4)
     qid = jnp.arange(16, dtype=jnp.int32)
     big_k = stream_kernel.MAX_UNROLLED_K + 1
@@ -162,7 +164,7 @@ def test_oversized_k_fallback_logs_once(monkeypatch, caplog):
     assert len(hits) == 1, [rec.message for rec in caplog.records]
     # a mode that never wanted the kernel (explicit ref) stays silent
     caplog.clear()
-    monkeypatch.setattr(stream_ops, "_oversized_k_warned", False)
+    monkeypatch.setattr(topk_ops, "_oversized_k_warned", set())
     with caplog.at_level("WARNING", logger="repro.kernels.knn_stream.ops"):
         q = jnp.asarray(r.normal(size=(16, 4)), jnp.float32)
         c = jnp.asarray(r.normal(size=(40, 4)), jnp.float32)
@@ -170,6 +172,37 @@ def test_oversized_k_fallback_logs_once(monkeypatch, caplog):
             q, c, qid, jnp.arange(40, dtype=jnp.int32),
             jnp.float32(1e9), k=big_k, mode="ref")
     assert not [r2 for r2 in caplog.records if "MAX_UNROLLED_K" in r2.message]
+
+
+def test_knn_topk_oversized_k_logs_once_at_boundary(monkeypatch, caplog):
+    """The brute lane's tile kernel shares the log-once helper: k =
+    MAX_UNROLLED_K runs the Pallas kernel silently, k = MAX_UNROLLED_K+1
+    reroutes to the jnp reference with exactly one warning across
+    traces, and both sides stay exact."""
+    monkeypatch.setattr(topk_ops, "_oversized_k_warned", set())
+    r = np.random.default_rng(5)
+    kmax = topk_kernel.MAX_UNROLLED_K
+    q = jnp.asarray(r.normal(size=(24, 4)), jnp.float32)
+    qid = jnp.arange(24, dtype=jnp.int32)
+
+    def run(k, n_c):
+        c = jnp.asarray(r.normal(size=(n_c, 4)), jnp.float32)
+        cid = jnp.arange(n_c, dtype=jnp.int32)
+        kd, ki = topk_ops.knn_topk(q, c, qid, cid, k=k, mode="interpret")
+        wd, wi = oracle_knn(c, q, k=k, squared=True, exclude_self=True)
+        np.testing.assert_allclose(np.asarray(kd), wd, rtol=1e-4, atol=1e-5)
+        np.testing.assert_array_equal(np.asarray(ki), wi)
+
+    with caplog.at_level("WARNING", logger="repro.kernels.knn_topk.ops"):
+        run(kmax, 72)
+        assert not [x for x in caplog.records
+                    if "MAX_UNROLLED_K" in x.message]
+        for n_c in (72, 80):   # two shapes → two traces, one line
+            run(kmax + 1, n_c)
+    hits = [x for x in caplog.records if "MAX_UNROLLED_K" in x.message]
+    assert len(hits) == 1, [x.message for x in caplog.records]
+    assert hits[0].name == "repro.kernels.knn_topk.ops"
+    assert "knn_topk" in hits[0].message
 
 
 # ---------------------------------------------------------------------------

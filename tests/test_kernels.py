@@ -190,3 +190,25 @@ def test_knn_topk_oversized_k_falls_back_to_ref():
             jnp.zeros((128, 8)), jnp.zeros((256, 8)),
             jnp.zeros((128,), jnp.int32), jnp.zeros((256,), jnp.int32),
             k=k, interpret=True)
+
+
+@pytest.mark.parametrize("fn", ["pairwise", "topk"])
+def test_l2_kernels_exact_under_cancellation(fn):
+    """Points far from the origin relative to their spacing: the MXU
+    expansion ‖q‖²+‖c‖²−2q·c cancels its f32 digits there, the direct
+    differences the kernels sum do not."""
+    r = np.random.default_rng(8)
+    base = r.normal(size=(1, 18)) * 4.0
+    qa = (base + 1e-2 * r.normal(size=(40, 18))).astype(np.float32)
+    ca = (base + 1e-2 * r.normal(size=(200, 18))).astype(np.float32)
+    diff = qa.astype(np.float64)[:, None, :] - ca.astype(np.float64)[None]
+    want = np.sum(diff * diff, axis=-1)
+    if fn == "pairwise":
+        got = np.asarray(pl_ops.pairwise_sq_l2(qa, ca, mode="interpret"))
+    else:
+        got, _ = kt_ops.knn_topk(
+            jnp.asarray(qa), jnp.asarray(ca),
+            jnp.arange(40, dtype=jnp.int32),
+            jnp.arange(40, 240, dtype=jnp.int32), k=8, mode="interpret")
+        got, want = np.asarray(got), np.sort(want, axis=1)[:, :8]
+    np.testing.assert_allclose(got, want, rtol=1e-5)

@@ -1,6 +1,7 @@
 """Work-queue scheduler + JoinSession: exactness oracle, scheduling
 invariants, and the compile-count probe (ISSUE 1 acceptance tests)."""
 import math
+import time
 
 import jax.numpy as jnp
 import numpy as np
@@ -219,6 +220,36 @@ def test_scheduler_no_demotion_when_dense_is_cheap():
         home_counts=home, dense_fn=dense_fn, sparse_fn=sparse_fn,
         brute_fn=brute_fn, n_batches=8, online_rebalance=True)
     assert rep.n_rebalanced == 0
+
+
+def test_engine_clock_excludes_compiles_in_flight():
+    """A sparse round in flight while the host compiles another engine
+    times its execution, not the compiler (the engine contract's T₁)."""
+    handle = queue_lib.AsyncEngineCall((np.zeros(3),))
+    time.sleep(0.2)
+    queue_lib.charge_compile(0.2)        # the sleep was a compile
+    handle.get()
+    assert handle.elapsed < 0.1
+
+
+def test_cold_query_compiles_first_round_before_the_queue(monkeypatch):
+    """The first round's timings seed the online ρ rebalance, so a cold
+    query compiles that round's dense and sparse engines before the
+    queue dispatches anything — and times it as a warm query would."""
+    from repro.runtime import KNNIndex
+
+    pts = _clustered(seed=14)
+    index = KNNIndex.build(pts, HybridConfig(k=3, m=4, rho=0.3, n_batches=2))
+    seen = {}
+    run = queue_lib.run_work_queue
+
+    def spy(**kw):
+        seen.update(index.compile_counts)
+        return run(**kw)
+
+    monkeypatch.setattr(queue_lib, "run_work_queue", spy)
+    index.query(pts + np.float32(0.01))
+    assert seen.get("dense", 0) >= 1 and seen.get("sparse", 0) >= 1
 
 
 # ---------------------------------------------------------------------------

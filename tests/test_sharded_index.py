@@ -173,8 +173,7 @@ def test_merge_strategies_agree():
 def test_zero_compile_steady_state_per_mesh_shape():
     """Same-bucket repeat queries on a sharded index must compile zero
     new engines — including the collective merge — on every mesh shape
-    (and equal shard shapes mean P shards share ONE engine set: the
-    merge compiles exactly once per (shape-bucket, k))."""
+    (the merge compiles exactly once per (shape-bucket, k))."""
     run_devices("""
         db = make_db(seed=7, n_core=280, n_bg=120)
         q = make_queries(seed=8, n=120)
@@ -194,6 +193,33 @@ def test_zero_compile_steady_state_per_mesh_shape():
             index.query(exclude_self=True)
             again = index.query(exclude_self=True)
             assert again.stats.n_engine_compiles == 0, n_shards
+    """)
+
+
+def test_shards_run_on_their_own_devices():
+    """Each shard's points, grid and pyramid are committed to a distinct
+    device of the mesh, and its engines compile for that device — not
+    every shard on the default device."""
+    run_devices("""
+        from repro.runtime.knn_index import _ENGINE_CACHE
+        db = make_db(seed=11)
+        cfg = HybridConfig(k=3, m=4, backend="ref")
+        index = KNNIndex.build(db, cfg, mesh=make_serving_mesh(4))
+        owners = []
+        for shard in index.shards:
+            leaves = jax.tree_util.tree_leaves(
+                (shard.points_r, shard.grid, shard.pyramid))
+            devs = {d for leaf in leaves for d in leaf.sharding.device_set}
+            assert len(devs) == 1, devs
+            owners.append(devs.pop())
+        assert len(set(owners)) == 4, owners
+        index.query(make_queries(seed=12))
+        engine_devs = {
+            frozenset(sh.device_set)
+            for key, ex in _ENGINE_CACHE.items() if key[0] != "merge"
+            for sh in jax.tree_util.tree_leaves(ex.input_shardings)}
+        for d in owners:
+            assert frozenset([d]) in engine_devs, (d, engine_devs)
     """)
 
 

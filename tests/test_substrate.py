@@ -1,5 +1,9 @@
-"""Substrate tests: checkpoint, pipeline, optimizer, runtime."""
+"""Substrate tests: checkpoint, pipeline, optimizer, runtime, compile
+cache."""
 import os
+import subprocess
+import sys
+import textwrap
 
 import jax
 import jax.numpy as jnp
@@ -278,3 +282,39 @@ def test_supervisor_gives_up_on_poison_step():
     _, report = sup.run(0, step_fn, 0, 5)
     assert not report.completed
     assert len(report.failures) >= 2
+
+
+# ---------------------------------------------------------------------------
+# compile cache
+# ---------------------------------------------------------------------------
+
+_CACHE_PROBE = textwrap.dedent("""
+    import os, jax, jax.numpy as jnp
+    from repro.utils import REPO_ROOT, init_compile_cache
+    path = init_compile_cache()
+    assert jax.config.jax_compilation_cache_dir == path, path
+    if "JAX_COMPILATION_CACHE_DIR" in os.environ:
+        assert path == os.environ["JAX_COMPILATION_CACHE_DIR"], path
+        jax.jit(lambda x: jnp.sin(x) * 2)(jnp.ones(8)).block_until_ready()
+        assert os.listdir(path), "nothing was cached"
+    else:
+        assert path == str(REPO_ROOT / ".jax_cache"), path
+""")
+
+
+@pytest.mark.parametrize("from_env", [True, False], ids=["env", "default"])
+def test_init_compile_cache_location(tmp_path, from_env):
+    """The compile cache goes where JAX_COMPILATION_CACHE_DIR says, and
+    otherwise to the fixed <repo>/.jax_cache (run in a child process so
+    this one's cache settings stay untouched)."""
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env["PYTHONPATH"] = os.path.join(root, "src")
+    env["JAX_PLATFORMS"] = "cpu"
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+        env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
+    proc = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-3000:]
