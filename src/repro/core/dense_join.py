@@ -132,6 +132,13 @@ class DenseJoinResult(NamedTuple):
     found: jnp.ndarray     # (Q,) i32 neighbors within ε (self excluded)
     failed: jnp.ndarray    # (Q,) bool — < K within ε, or candidate overflow
     total_candidates: jnp.ndarray  # (Q,) i32 — filtering workload (T₂ proxy)
+    # Work counters, each a 0-d i32 over the real rows (query id ≥ 0).
+    # One call scores at most rows × nblk·block_c pairs (~40 M for a
+    # 16,384-row batch at the 2,048 budget), far inside int32.
+    n_overflow: jnp.ndarray   # rows failed by candidate-budget overflow
+    pairs: jnp.ndarray        # query–candidate pairs the backend scores
+    cand_rows: jnp.ndarray    # candidate rows it reads (per tile, or per
+                              # query on "ref")
 
 
 def _exclusion_ids(qids, exclude_self: bool):
@@ -184,7 +191,9 @@ def _block_fn(index: grid_lib.GridIndex, points_r, eps2, k, budget,
         )
         found = jnp.sum(keep, axis=1).astype(jnp.int32)
         failed = (found < k) | overflow
-        return kdists, kids, found, failed, total.astype(jnp.int32)
+        n_read = jnp.sum(valid, axis=1).astype(jnp.int32)
+        return (kdists, kids, found, failed, total.astype(jnp.int32),
+                overflow, n_read)
 
     return fn
 
@@ -335,7 +344,7 @@ def _fused_prefetch_join(index: grid_lib.GridIndex, points_r, qids, eps2, k,
     kernel pulls its own candidates from the HBM-resident cell-sorted
     corpus.  No gathered (tiles, budget, D) candidate copy exists at any
     layer.  Returns (kd, ki, found, failed, total), already scattered
-    back to original query order."""
+    back to original query order, then the ``_tile_counters``."""
     queries = points_r if queries_r is None else queries_r
     coords_all = index.point_coords if qcoords is None else qcoords
     tiles, perm = grid_lib.group_queries_by_cell(
@@ -385,7 +394,19 @@ def _fused_prefetch_join(index: grid_lib.GridIndex, points_r, qids, eps2, k,
         failed_rows = found < k
     failed = failed_rows | jnp.repeat(tile_ovf, query_block)
     out = (kd, ki, found, failed, own_total.reshape(-1))
-    return tuple(jnp.zeros_like(x).at[perm].set(x) for x in out)
+    return (tuple(jnp.zeros_like(x).at[perm].set(x) for x in out)
+            + _tile_counters(tiles, tile_ovf, jnp.sum(cand >= 0, axis=1)))
+
+
+def _tile_counters(tiles, tile_ovf, n_cand):
+    """The work counters of cell-sorted tiles ``tiles`` (T, TQ), whose
+    shared candidate blocks overflowed as ``tile_ovf`` (T,) and hold
+    ``n_cand`` (T,) candidate ids: (rows failed by overflow, pairs,
+    candidate rows), each a 0-d i32."""
+    rows = jnp.sum(tiles >= 0, axis=1, dtype=jnp.int32)
+    n_cand = n_cand.astype(jnp.int32)
+    return (jnp.sum(jnp.where(tile_ovf, rows, 0)), jnp.sum(rows * n_cand),
+            jnp.sum(n_cand))
 
 
 def _tile_fn(index: grid_lib.GridIndex, points_r, eps2, k, budget, block_c,
@@ -431,7 +452,8 @@ def _tile_fn(index: grid_lib.GridIndex, points_r, eps2, k, budget, block_c,
         # The shared block holds the tile's union, so truncation hits every
         # query in the tile at once — a per-tile §V-E failure.
         failed = (found < k) | tile_overflow
-        return kdists, kids, found, failed, own_total
+        return (kdists, kids, found, failed, own_total), (
+            tile_overflow, jnp.sum(cand_ids >= 0))
 
     return fn
 
@@ -461,7 +483,8 @@ def _fused_tile_fn(index: grid_lib.GridIndex, points_r, eps2, k, budget,
         )
         # Same per-tile §V-E overflow semantics as the two-pass tiled path.
         failed = (found < k) | tile_overflow
-        return kdists, kids, found, failed, own_total
+        return (kdists, kids, found, failed, own_total), (
+            tile_overflow, jnp.sum(cand_ids >= 0))
 
     return fn
 
@@ -589,11 +612,15 @@ def dense_join_jit(
                       queries_r, qcoords, exclude_self, metric),
             blocks,
         )
-        kd, ki, found, failed, total = jax.tree_util.tree_map(
+        kd, ki, found, failed, total, ovf, n_read = jax.tree_util.tree_map(
             lambda x: x.reshape((qpad,) + x.shape[2:]), out
         )
+        real = qids >= 0
+        n_read = jnp.where(real, n_read, 0)
+        counters = (jnp.sum(real & ovf, dtype=jnp.int32), jnp.sum(n_read),
+                    jnp.sum(n_read))
     elif use_prefetch:
-        kd, ki, found, failed, total = _fused_prefetch_join(
+        kd, ki, found, failed, total, *counters = _fused_prefetch_join(
             index, points_r, qids, eps2, k, budget, query_block, block_c,
             _stream_kernel_mode(), queries_r, qcoords, exclude_self,
             metric, distance_dtype,
@@ -613,7 +640,8 @@ def dense_join_jit(
         tiles, perm = grid_lib.group_queries_by_cell(
             index, qids, query_block, qcoords
         )
-        out = jax.lax.map(tile_fn, tiles)
+        out, (tile_ovf, n_cand) = jax.lax.map(tile_fn, tiles)
+        counters = _tile_counters(tiles, tile_ovf, n_cand)
         kd, ki, found, failed, total = jax.tree_util.tree_map(
             lambda x: jnp.zeros_like(x.reshape((qpad,) + x.shape[2:]))
             .at[perm]
@@ -623,4 +651,5 @@ def dense_join_jit(
     n = query_ids.shape[0]
     pad_row = jnp.arange(qpad) >= n
     failed = failed | pad_row | (qids < 0)
-    return DenseJoinResult(kd[:n], ki[:n], found[:n], failed[:n], total[:n])
+    return DenseJoinResult(kd[:n], ki[:n], found[:n], failed[:n], total[:n],
+                           *counters)

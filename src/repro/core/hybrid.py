@@ -29,7 +29,7 @@ load-balance point.
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -180,6 +180,22 @@ class JoinStats:
     n_sparse_engine_total: int = 0  # all queries the sparse engine processed
     rho_online: float = 0.0       # last Eq. 6 estimate the scheduler applied
     n_engine_compiles: int = 0    # engine compilations triggered by this join
+    # engine counters, reduced on the device (0 where an engine did not run)
+    n_dense_overflow: int = 0     # real dense rows failed by candidate-
+                                  # budget overflow (the rest of n_failed
+                                  # found < K within ε)
+    dense_pairs: int = 0          # query–candidate pairs the dense kernel
+                                  # scored
+    dense_cand_rows: int = 0      # candidate rows the dense kernel read
+    n_sparse_overflow: int = 0    # uncertified sparse rows whose budget
+                                  # overflowed (the rest missed the level's
+                                  # certified radius)
+    # programs JAX compiled or loaded from its cache during the call,
+    # helpers and engines alike (runtime/trace.py)
+    n_programs_compiled: int = 0
+    t_compile: float = 0.0        # their seconds
+    compiles_by_span: Dict[str, int] = dataclasses.field(
+        default_factory=dict)     # innermost knn.* span -> programs
     # fault-tolerant serving accounting (DESIGN.md §7) — populated by the
     # sharded replica-group path; zero/empty on single-device queries.
     n_hedged: int = 0             # slow sub-queries re-issued to a sibling

@@ -45,6 +45,7 @@ import jax
 import numpy as np
 
 from repro.core import splitter as split_lib
+from repro.runtime import trace
 
 _compile_seconds = 0.0      # host seconds spent compiling engines
 
@@ -90,10 +91,12 @@ class AsyncEngineCall:
         return True
 
     def get(self):
-        jax.block_until_ready(self._raw)
+        with trace.span("knn.sparse.wait"):
+            jax.block_until_ready(self._raw)
         if self.elapsed is None:
             self.elapsed = engine_clock() - self.t_dispatch
-        return self._finalize(self._raw)
+        with trace.span("knn.sparse.copy"):
+            return self._finalize(self._raw)
 
 
 @dataclasses.dataclass
@@ -116,6 +119,14 @@ class QueueReport:
     t1_per_query: float = 0.0        # paper T₁ (sparse engine)
     t2_per_query: float = 0.0        # paper T₂ (dense engine)
     rho_online: float = 0.0          # last Eq. 6 estimate used for demotion
+    # Engine counters, each reduced to a scalar on the device and added
+    # here by the engine closures' harvests (0 for stub engines).
+    n_dense_overflow: int = 0        # real dense rows failed by overflow
+    dense_pairs: int = 0             # query–candidate pairs the dense
+                                     # kernel scored
+    dense_cand_rows: int = 0         # candidate rows it read
+    n_sparse_overflow: int = 0       # uncertified sparse rows whose
+                                     # budget overflowed
 
 
 class WorkQueue:
@@ -198,6 +209,7 @@ def run_work_queue(
     sync_t1_after: int = 1,
     min_sparse: int = 0,
     demote_quantum: int = 1,
+    report: Optional[QueueReport] = None,
 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, QueueReport]:
     """Drive one join through the multi-round queue.
 
@@ -222,7 +234,9 @@ def run_work_queue(
     batches if the async poll has not succeeded yet (0 disables), so the
     rebalance point is deterministic across backends.  ``demote_quantum``
     is the minimum online demotion (one engine query block): deficits
-    smaller than it are not worth a dedicated sparse round.
+    smaller than it are not worth a dedicated sparse round.  ``report``
+    is the record to fill, one the engines' harvests also add their
+    counters to; a fresh one by default.
 
     Returns ``(final_d, final_i, source, report)`` with ``final_d`` in
     squared-L2 (callers sqrt), ``source`` ∈ {0: dense, 1: sparse,
@@ -240,7 +254,7 @@ def run_work_queue(
     final_d = np.full((npts, k), np.inf, np.float32)
     final_i = np.full((npts, k), -1, np.int32)
     source = np.full((npts,), 1, np.int8)
-    report = QueueReport()
+    report = QueueReport() if report is None else report
 
     queue = WorkQueue(dense_ids, home_counts, n_batches)
     backlog: List[np.ndarray] = []     # demoted, awaiting a sparse round
@@ -257,7 +271,8 @@ def run_work_queue(
         feed the T₁ load model."""
         nonlocal inflight
         t0 = engine_clock()
-        inflight = (ids, sparse_fn(ids), t0, pure)
+        with trace.span("knn.sparse.dispatch"):
+            inflight = (ids, sparse_fn(ids), t0, pure)
         report.n_sparse_rounds += 1
         report.n_sparse_engine_total += len(ids)
 
@@ -316,21 +331,23 @@ def run_work_queue(
             and t2 is not None
             and queue.remaining
         ):
-            rho_online = split_lib.rho_model(t1, t2)
-            report.rho_online = rho_online
-            assigned = report.n_sparse_engine_total + sum(
-                len(b) for b in backlog
-            )
-            deficit = int(math.ceil(rho_online * npts)) - assigned
-            # Slivers below one engine block aren't worth a round; the
-            # engine-side _pad_ids pow2 padding bounds compiled shapes.
-            if deficit < queue.remaining and deficit < max(demote_quantum, 1):
-                deficit = 0
-            if deficit > 0:
-                demoted = queue.demote(deficit)
-                if len(demoted):
-                    backlog.append(demoted)
-                    report.n_rebalanced += len(demoted)
+            with trace.span("knn.queue.rebalance"):
+                rho_online = split_lib.rho_model(t1, t2)
+                report.rho_online = rho_online
+                assigned = report.n_sparse_engine_total + sum(
+                    len(b) for b in backlog
+                )
+                deficit = int(math.ceil(rho_online * npts)) - assigned
+                # Slivers below one engine block aren't worth a round; the
+                # engine-side _pad_ids pow2 padding bounds compiled shapes.
+                if (deficit < queue.remaining
+                        and deficit < max(demote_quantum, 1)):
+                    deficit = 0
+                if deficit > 0:
+                    demoted = queue.demote(deficit)
+                    if len(demoted):
+                        backlog.append(demoted)
+                        report.n_rebalanced += len(demoted)
 
         if inflight is None and backlog:
             dispatch_sparse(_concat(backlog))

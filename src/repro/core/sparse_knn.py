@@ -76,6 +76,8 @@ class SparseKNNResult(NamedTuple):
     certified: jnp.ndarray    # (Q,) bool — exactness proven at chosen level
     level: jnp.ndarray        # (Q,) i32 — pyramid level used
     total_candidates: jnp.ndarray  # (Q,) i32 — work proxy (T₁ numerator)
+    n_overflow: jnp.ndarray   # () i32 — real rows left uncertified whose
+                              # budget overflowed at their final level
 
 
 def _gathered_sq_l2(qpts, cand_pts, metric="l2"):
@@ -269,7 +271,7 @@ def _block_fn(pyr: Pyramid, points_r, k, budget, sel_factor, backend,
         sel2 = jnp.searchsorted(cert_r2, kth1).astype(jnp.int32)
         sel2 = jnp.clip(jnp.maximum(sel2, sel1), 0, n_levels - 1)
 
-        kd2, ki2, cert2, _, tot2 = _query_level(
+        kd2, ki2, cert2, ovf2, tot2 = _query_level(
             pyr, points_r, queries, orders, starts, counts, qids, excl,
             safe, sel2, k, budget, backend, metric, distance_dtype
         )
@@ -279,7 +281,9 @@ def _block_fn(pyr: Pyramid, points_r, k, budget, sel_factor, backend,
         ki = jnp.where(use1, ki1, ki2)
         certified = cert1 | cert2
         level = jnp.where(cert1, sel1, sel2)
-        return kd, ki, certified, level, tot1 + jnp.where(cert1, 0, tot2)
+        overflowed = ~certified & ovf2 & (qids >= 0)
+        return (kd, ki, certified, level, tot1 + jnp.where(cert1, 0, tot2),
+                overflowed)
 
     return fn
 
@@ -354,8 +358,9 @@ def sparse_knn_jit(
                   queries_r, exclude_self, metric, distance_dtype),
         blocks,
     )
-    kd, ki, cert, lvl, total = jax.tree_util.tree_map(
+    kd, ki, cert, lvl, total, ovf = jax.tree_util.tree_map(
         lambda x: x.reshape((qpad,) + x.shape[2:]), out
     )
     n = query_ids.shape[0]
-    return SparseKNNResult(kd[:n], ki[:n], cert[:n], lvl[:n], total[:n])
+    return SparseKNNResult(kd[:n], ki[:n], cert[:n], lvl[:n], total[:n],
+                           jnp.sum(ovf, dtype=jnp.int32))

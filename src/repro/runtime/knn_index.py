@@ -59,6 +59,7 @@ from repro.kernels import f32_precision
 from repro.retrieval import metrics as met_lib
 from repro.retrieval import projection as proj_lib
 from repro.runtime import mutation as mut_lib
+from repro.runtime import trace
 from repro.utils import pad_to, pow2_bucket
 
 # Process-global AOT executable cache: key -> jax.stages.Compiled.
@@ -104,6 +105,25 @@ def run_engine(owner, kind: str, jitted, args: tuple, kwargs: dict):
         owner.compile_counts[kind] = owner.compile_counts.get(kind, 0) + 1
     owner.executables[kind] = ex
     return ex
+
+
+def traced_query(query):
+    """Wrap an index's ``query``: one ``knn.query`` span over the call,
+    and the programs JAX compiled or loaded during it (any thread; see
+    ``runtime/trace.py``) in the result's stats."""
+
+    @functools.wraps(query)
+    def traced(*args, **kwargs):
+        before = trace.snapshot()
+        with trace.span("knn.query"):
+            res = query(*args, **kwargs)
+        compiles = trace.since(before)
+        res.stats.n_programs_compiled = compiles.programs
+        res.stats.t_compile = compiles.seconds
+        res.stats.compiles_by_span = compiles.by_span
+        return res
+
+    return traced
 
 
 def validate_points(arr, n_dims: Optional[int], what: str = "queries"):
@@ -662,7 +682,10 @@ class KNNIndex:
         return met_lib.kernel_metric(self.config.metric)
 
     def _dense_fn(self, gen: _Generation, k: int, queries_rp,
-                  exclude_self: bool, eps_scale: Optional[float] = None):
+                  exclude_self: bool, report: queue_lib.QueueReport,
+                  eps_scale: Optional[float] = None):
+        """The dense engine over query ids; each harvest adds the
+        engine's work counters to ``report``."""
         cfg = self.config
         # ε is a RUNTIME operand: the approximate mode's scaled ε
         # (DESIGN.md §9.4) reuses the exact path's executable.
@@ -684,20 +707,30 @@ class KNNIndex:
             if compile_only:
                 return None
             t0 = time.perf_counter()
-            res = jax.block_until_ready(ex(*args))
+            with trace.span("knn.dense.wait"):
+                res = jax.block_until_ready(ex(*args))
             dt = time.perf_counter() - t0
             n = len(ids)
-            return (
-                np.asarray(res.dists[:n]),
-                np.asarray(res.ids[:n]),
-                np.asarray(res.failed[:n]),
-                dt,
-            )
+            with trace.span("knn.dense.copy"):
+                out = (
+                    np.asarray(res.dists[:n]),
+                    np.asarray(res.ids[:n]),
+                    np.asarray(res.failed[:n]),
+                    dt,
+                )
+                ovf, pairs, cand_rows = jax.device_get(
+                    (res.n_overflow, res.pairs, res.cand_rows))
+            report.n_dense_overflow += int(ovf)
+            report.dense_pairs += int(pairs)
+            report.dense_cand_rows += int(cand_rows)
+            return out
 
         return dense_fn
 
     def _sparse_fn(self, gen: _Generation, k: int, queries_rp,
-                   exclude_self: bool):
+                   exclude_self: bool, report: queue_lib.QueueReport):
+        """The sparse engine over query ids, dispatched async; each
+        harvest adds its overflow count to ``report``."""
         cfg = self.config
 
         def sparse_fn(ids: np.ndarray, compile_only: bool = False):
@@ -719,6 +752,7 @@ class KNNIndex:
             n = len(ids)
 
             def finalize(r):
+                report.n_sparse_overflow += int(r.n_overflow)
                 return (
                     np.asarray(r.dists[:n]),
                     np.asarray(r.ids[:n]),
@@ -743,12 +777,17 @@ class KNNIndex:
                 kernel_mode=cfg.kernel_mode, exclude_self=exclude_self,
                 metric=self._grid_metric(gen),
             )
-            ex = self._engine("brute", _brute_engine, args, kwargs)
-            d, i = jax.block_until_ready(ex(*args))
-            n = len(ids)
-            return np.asarray(d[:n]), np.asarray(i[:n])
+            return self._brute_call(args, kwargs, len(ids))
 
         return brute_fn
+
+    def _brute_call(self, args: tuple, kwargs: dict, n: int):
+        """Run the brute engine and harvest its first ``n`` rows."""
+        ex = self._engine("brute", _brute_engine, args, kwargs)
+        with trace.span("knn.brute.wait"):
+            d, i = jax.block_until_ready(ex(*args))
+        with trace.span("knn.brute.copy"):
+            return np.asarray(d[:n]), np.asarray(i[:n])
 
     def _full_brute_fn(self, gen: _Generation, k: int, queries_fp,
                        exclude_self: bool):
@@ -769,10 +808,7 @@ class KNNIndex:
                 kernel_mode=cfg.kernel_mode, exclude_self=exclude_self,
                 metric=met_lib.kernel_metric(cfg.metric),
             )
-            ex = self._engine("brute", _brute_engine, args, kwargs)
-            d, i = jax.block_until_ready(ex(*args))
-            n = len(ids)
-            return np.asarray(d[:n]), np.asarray(i[:n])
+            return self._brute_call(args, kwargs, len(ids))
 
         return brute_fn
 
@@ -900,8 +936,10 @@ class KNNIndex:
         merge-time folding, so folds compare like with like)."""
         cfg = self.config
         rho_floor = cfg.rho if rho is None else rho
-        dense_fn = self._dense_fn(gen, kq, queries_rp, exclude_self)
-        sparse_fn = self._sparse_fn(gen, kq, queries_rp, exclude_self)
+        report = queue_lib.QueueReport()
+        dense_fn = self._dense_fn(gen, kq, queries_rp, exclude_self, report)
+        sparse_fn = self._sparse_fn(gen, kq, queries_rp, exclude_self,
+                                    report)
         # The first round's timings seed the online ρ rebalance, so they
         # must not depend on the engine cache: compile that round's
         # engines before the queue dispatches any.  (Were the dense
@@ -910,11 +948,12 @@ class KNNIndex:
         # differently from a warm one, and the warm one would compile
         # the buckets its demotions land in.)
         t0 = time.perf_counter()
-        if len(sparse_ids):
-            sparse_fn(sparse_ids, compile_only=True)
-        first = -(-len(dense_ids) // cfg.n_batches)
-        if first:
-            dense_fn(dense_ids[:first], compile_only=True)
+        with trace.span("knn.prepare"):
+            if len(sparse_ids):
+                sparse_fn(sparse_ids, compile_only=True)
+            first = -(-len(dense_ids) // cfg.n_batches)
+            if first:
+                dense_fn(dense_ids[:first], compile_only=True)
         t_prepare = time.perf_counter() - t0
         *answers, report = queue_lib.run_work_queue(
             npts=n_q,
@@ -930,6 +969,7 @@ class KNNIndex:
             sync_t1_after=cfg.rebalance_sync_batches,
             min_sparse=int(math.ceil(rho_floor * n_q)),
             demote_quantum=cfg.query_block,
+            report=report,
         )
         report.t_wall += t_prepare      # the query's wall holds its compiles
         return (*answers, report)
@@ -965,8 +1005,13 @@ class KNNIndex:
             n_sparse_engine_total=report.n_sparse_engine_total,
             rho_online=report.rho_online,
             n_engine_compiles=self.total_compiles - compiles_before,
+            n_dense_overflow=report.n_dense_overflow,
+            dense_pairs=report.dense_pairs,
+            dense_cand_rows=report.dense_cand_rows,
+            n_sparse_overflow=report.n_sparse_overflow,
         )
 
+    @traced_query
     def query(
         self,
         queries=None,
@@ -1048,36 +1093,39 @@ class KNNIndex:
             return self._query_brute_all(
                 gen, kq, n_q, q_np, exclude_self, compiles_before)
 
-        if is_self:
-            queries_rp = None
-            dense_ids, sparse_ids, threshold = self._self_split(gen, kq, rho)
-            home_counts = gen.home_counts
-        else:
-            q = jnp.asarray(q_np)
-            queries_r = q[:, gen.dim_perm] if gen.dim_perm is not None else q
-            # The query-shape bucket: engine-cache keys see this padded
-            # aval, so variable batch sizes share executables.
-            queries_rp = pad_rows_pow2(queries_r, cfg.query_block)
-            q_coords = grid_lib.compute_cell_coords(
-                gen.grid, queries_r[:, : gen.grid.m]
-            )
-            net_adjust = None
-            if _net_cells is not None:
-                q_cells = np.asarray(
-                    grid_lib.linearize(q_coords, gen.grid.radices)
+        with trace.span("knn.split"):
+            if is_self:
+                queries_rp = None
+                dense_ids, sparse_ids, threshold = self._self_split(
+                    gen, kq, rho)
+                home_counts = gen.home_counts
+            else:
+                q = jnp.asarray(q_np)
+                queries_r = (q[:, gen.dim_perm] if gen.dim_perm is not None
+                             else q)
+                # The query-shape bucket: engine-cache keys see this padded
+                # aval, so variable batch sizes share executables.
+                queries_rp = pad_rows_pow2(queries_r, cfg.query_block)
+                q_coords = grid_lib.compute_cell_coords(
+                    gen.grid, queries_r[:, : gen.grid.m]
                 )
-                net_adjust = jnp.asarray(mut_lib.net_cell_adjustment(
-                    gen.grid, q_cells, *_net_cells
-                ))
-            split = split_lib.split_queries(
-                gen.grid, q_coords, kq, cfg.gamma, rho,
-                net_adjust=net_adjust,
-            )
-            to_dense = np.asarray(split.to_dense)
-            dense_ids = np.nonzero(to_dense)[0].astype(np.int32)
-            sparse_ids = np.nonzero(~to_dense)[0].astype(np.int32)
-            home_counts = np.asarray(split.home_counts)
-            threshold = float(split.threshold)
+                net_adjust = None
+                if _net_cells is not None:
+                    q_cells = np.asarray(
+                        grid_lib.linearize(q_coords, gen.grid.radices)
+                    )
+                    net_adjust = jnp.asarray(mut_lib.net_cell_adjustment(
+                        gen.grid, q_cells, *_net_cells
+                    ))
+                split = split_lib.split_queries(
+                    gen.grid, q_coords, kq, cfg.gamma, rho,
+                    net_adjust=net_adjust,
+                )
+                to_dense = np.asarray(split.to_dense)
+                dense_ids = np.nonzero(to_dense)[0].astype(np.int32)
+                sparse_ids = np.nonzero(~to_dense)[0].astype(np.int32)
+                home_counts = np.asarray(split.home_counts)
+                threshold = float(split.threshold)
 
         if cfg.recall_target < 1.0 and _net_cells is None:
             return self._query_approx(
@@ -1089,16 +1137,17 @@ class KNNIndex:
             gen, kq, n_q, queries_rp, dense_ids, sparse_ids, home_counts,
             exclude_self, rho=rho,
         )
-        stats = self._stats(
-            gen, len(dense_ids), len(sparse_ids), threshold, report,
-            compiles_before,
-        )
-        return hybrid_lib.KNNResult(
-            dists=met_lib.finalize(final_d, cfg.metric),
-            ids=final_i,
-            source=source,
-            stats=stats,
-        )
+        with trace.span("knn.finalize"):
+            stats = self._stats(
+                gen, len(dense_ids), len(sparse_ids), threshold, report,
+                compiles_before,
+            )
+            return hybrid_lib.KNNResult(
+                dists=met_lib.finalize(final_d, cfg.metric),
+                ids=final_i,
+                source=source,
+                stats=stats,
+            )
 
     # -- metric / approximation query paths (DESIGN.md §9) -----------------
 
@@ -1176,43 +1225,39 @@ class KNNIndex:
         operand — the exact path's executable, zero recompiles), then
         NO failure reassignment and NO brute certification — the missing
         backstops are what the calibrated tier's measured recall pays
-        for."""
+        for.  The report's engine counters are filled as on the exact
+        path; ``n_uncertified`` counts rows that no brute lane serves."""
         d_out = np.full((n_q, kq), np.inf, np.float32)
         i_out = np.full((n_q, kq), -1, np.int32)
         source = np.zeros((n_q,), np.int32)
+        report = queue_lib.QueueReport()
         t0 = time.perf_counter()
-        t_dense = t_sparse = 0.0
-        n_failed = n_uncert = 0
         call = None
         if len(sparse_ids):
-            call = self._sparse_fn(gen, kq, queries_rp, exclude_self)(
-                sparse_ids)
+            call = self._sparse_fn(gen, kq, queries_rp, exclude_self,
+                                   report)(sparse_ids)
         if len(dense_ids):
             dd, di, dfail, t_dense = self._dense_fn(
-                gen, kq, queries_rp, exclude_self, eps_scale=eps_scale
+                gen, kq, queries_rp, exclude_self, report,
+                eps_scale=eps_scale,
             )(dense_ids)
             d_out[dense_ids] = dd
             i_out[dense_ids] = di
-            n_failed = int(np.sum(dfail))
+            report.n_failed = int(np.sum(dfail))
+            report.batch_sizes = [len(dense_ids)]
+            report.t_batches = [t_dense]
+            report.n_dense_batches = 1
+            report.t_dense = t_dense
         if call is not None:
             sd, si, cert = call.get()
-            t_sparse = call.elapsed or 0.0
             d_out[sparse_ids] = sd
             i_out[sparse_ids] = si
             source[sparse_ids] = 1
-            n_uncert = int(np.sum(~cert))
-        report = queue_lib.QueueReport(
-            batch_sizes=[len(dense_ids)] if len(dense_ids) else [],
-            t_batches=[t_dense] if len(dense_ids) else [],
-            n_dense_batches=1 if len(dense_ids) else 0,
-            n_sparse_rounds=1 if len(sparse_ids) else 0,
-            n_failed=n_failed,
-            n_uncertified=n_uncert,
-            n_sparse_engine_total=len(sparse_ids),
-            t_dense=t_dense,
-            t_sparse=t_sparse,
-            t_wall=time.perf_counter() - t0,
-        )
+            report.n_sparse_rounds = 1
+            report.n_uncertified = int(np.sum(~cert))
+            report.n_sparse_engine_total = len(sparse_ids)
+            report.t_sparse = call.elapsed or 0.0
+        report.t_wall = time.perf_counter() - t0
         return d_out, i_out, source, report
 
     def _query_approx(

@@ -60,7 +60,7 @@ from repro.runtime import mutation as mut_lib
 from repro.runtime.faults import FaultInjector
 from repro.runtime.knn_index import (
     _ENGINE_CACHE, KNNIndex, _engine_key, executable_memory_analysis,
-    pad_rows_pow2, run_engine, select_epsilon, validate_k,
+    pad_rows_pow2, run_engine, select_epsilon, traced_query, validate_k,
     validate_points,
 )
 from repro.runtime.serving import ServingConfig, ServingSupervisor
@@ -594,6 +594,7 @@ class ShardedKNNIndex:
 
     # -- the query pipeline ------------------------------------------------
 
+    @traced_query
     def query(
         self,
         queries=None,
@@ -978,6 +979,10 @@ class ShardedKNNIndex:
             n_sparse_rounds=sum(s.n_sparse_rounds for s in shard_stats),
             n_sparse_engine_total=sum(
                 s.n_sparse_engine_total for s in shard_stats),
+            n_dense_overflow=sum(s.n_dense_overflow for s in shard_stats),
+            dense_pairs=sum(s.dense_pairs for s in shard_stats),
+            dense_cand_rows=sum(s.dense_cand_rows for s in shard_stats),
+            n_sparse_overflow=sum(s.n_sparse_overflow for s in shard_stats),
             rho_online=float(np.mean(
                 [s.rho_online for s in shard_stats])),
             n_engine_compiles=self.total_compiles - compiles_before,

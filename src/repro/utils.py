@@ -1,11 +1,9 @@
-"""Small shared utilities: timing, padding, pytree dataclasses."""
+"""Small shared utilities: padding, pytree dataclasses."""
 from __future__ import annotations
 
 import dataclasses
 import os
 import pathlib
-import time
-from typing import Any, Callable, Iterable, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -103,36 +101,6 @@ def pad_to(x: jnp.ndarray, size: int, axis: int = 0, value=0):
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, size - cur)
     return jnp.pad(x, widths, constant_values=value)
-
-
-class Timer:
-    """Wall-clock timer that blocks on device results (for honest timings)."""
-
-    def __init__(self):
-        self.elapsed = 0.0
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self.elapsed = time.perf_counter() - self._t0
-        return False
-
-
-def timed(fn: Callable, *args, repeats: int = 1, warmup: int = 1, **kw):
-    """Run fn repeatedly, blocking until ready; return (best_seconds, result)."""
-    result = None
-    for _ in range(max(warmup, 0)):
-        result = fn(*args, **kw)
-        jax.block_until_ready(result)
-    best = float("inf")
-    for _ in range(max(repeats, 1)):
-        t0 = time.perf_counter()
-        result = fn(*args, **kw)
-        jax.block_until_ready(result)
-        best = min(best, time.perf_counter() - t0)
-    return best, result
 
 
 def tree_bytes(tree) -> int:
