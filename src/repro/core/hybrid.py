@@ -178,6 +178,8 @@ class JoinStats:
     n_rebalanced: int = 0         # queries demoted online beyond the ρ floor
     n_sparse_rounds: int = 0
     n_sparse_engine_total: int = 0  # all queries the sparse engine processed
+    n_sparse_gated: int = 0       # sparse-bound queries the capacity gate
+                                  # sent to the brute lane unseen
     rho_online: float = 0.0       # last Eq. 6 estimate the scheduler applied
     n_engine_compiles: int = 0    # engine compilations triggered by this join
     # engine counters, reduced on the device (0 where an engine did not run)

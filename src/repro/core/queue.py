@@ -111,6 +111,8 @@ class QueueReport:
     n_failed: int = 0                # dense failures reassigned (§V-E)
     n_uncertified: int = 0           # sparse results needing the brute lane
     n_sparse_engine_total: int = 0   # every query the sparse engine saw
+    n_sparse_gated: int = 0          # sparse-bound rows the capacity gate
+                                     # sent to the brute lane unseen
     t_dense: float = 0.0
     t_sparse: float = 0.0
     t_brute: float = 0.0
@@ -210,6 +212,7 @@ def run_work_queue(
     min_sparse: int = 0,
     demote_quantum: int = 1,
     report: Optional[QueueReport] = None,
+    sparse_ok: Optional[np.ndarray] = None,
 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, QueueReport]:
     """Drive one join through the multi-round queue.
 
@@ -238,6 +241,15 @@ def run_work_queue(
     is the record to fill, one the engines' harvests also add their
     counters to; a fresh one by default.
 
+    ``sparse_ok`` ((|Q|,) bool, optional) is the capacity gate: a row it
+    refuses is one the sparse engine can never certify, so every round
+    (initial, demoted backlog, terminal failures) sends it straight to
+    the brute lane instead, counted in ``n_sparse_gated`` and not in
+    ``n_sparse_engine_total`` or ``n_uncertified``.  A round the gate
+    empties is not dispatched.  The ρ floor and the online demotion
+    count gated rows with the sparse ones: both are outside the dense
+    lane.  None dispatches every row.
+
     Returns ``(final_d, final_i, source, report)`` with ``final_d`` in
     squared-L2 (callers sqrt), ``source`` ∈ {0: dense, 1: sparse,
     2: brute}.
@@ -260,6 +272,7 @@ def run_work_queue(
     backlog: List[np.ndarray] = []     # demoted, awaiting a sparse round
     failed: List[np.ndarray] = []      # dense failures (§V-E)
     uncertified: List[np.ndarray] = []
+    gated: List[np.ndarray] = []       # refused by sparse_ok
     inflight: Optional[Tuple[np.ndarray, AsyncEngineCall, float]] = None
     t1: Optional[float] = None
     t2: Optional[float] = None
@@ -270,6 +283,13 @@ def run_work_queue(
         dense failures — it still runs on the sparse engine but must not
         feed the T₁ load model."""
         nonlocal inflight
+        if sparse_ok is not None:
+            ok = sparse_ok[ids]
+            gated.append(ids[~ok])
+            report.n_sparse_gated += len(gated[-1])
+            ids = ids[ok]
+            if not len(ids):
+                return
         t0 = engine_clock()
         with trace.span("knn.sparse.dispatch"):
             inflight = (ids, sparse_fn(ids), t0, pure)
@@ -334,8 +354,9 @@ def run_work_queue(
             with trace.span("knn.queue.rebalance"):
                 rho_online = split_lib.rho_model(t1, t2)
                 report.rho_online = rho_online
-                assigned = report.n_sparse_engine_total + sum(
-                    len(b) for b in backlog
+                assigned = (
+                    report.n_sparse_engine_total + report.n_sparse_gated
+                    + sum(len(b) for b in backlog)
                 )
                 deficit = int(math.ceil(rho_online * npts)) - assigned
                 # Slivers below one engine block aren't worth a round; the
@@ -363,11 +384,12 @@ def run_work_queue(
         # Failures ride the sparse engine but are not "sparse work" for
         # the load model; pure=False keeps them out of T₁.
         dispatch_sparse(tail_ids, pure=False)
-        harvest_sparse()
+        if inflight is not None:
+            harvest_sparse()
 
     # Brute backstop — exactness regardless of parameter choices.
-    unc = _concat(uncertified)
-    report.n_uncertified = len(unc)
+    report.n_uncertified = int(sum(len(u) for u in uncertified))
+    unc = _concat(uncertified + gated)
     if len(unc):
         t0 = time.perf_counter()
         d, i = brute_fn(unc)

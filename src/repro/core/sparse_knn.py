@@ -34,10 +34,12 @@ of the dense engine's streaming kernel.
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import dense_join as dense_lib
 from repro.core import grid as grid_lib
@@ -68,6 +70,45 @@ def build_pyramid(
         levels.append(g)
         radii.append(jnp.min(g.cell_edge))
     return Pyramid(levels=tuple(levels), cert_radii=jnp.stack(radii))
+
+
+def levels_nest(pyr: Pyramid, level_scale: float) -> bool:
+    """Whether every level-ℓ cell is a union of level-0 cells: the same
+    ``mins`` on every level, ``level_scale`` a whole power of two, and
+    ``cell_edge[ℓ] == cell_edge[0]·level_scale^ℓ`` exactly (false where
+    the int32 id cap raised a level's edge).  Read once per build."""
+    s = float(level_scale)
+    if s < 1.0 or math.frexp(s)[0] != 0.5:
+        return False
+    mins, edges = jax.device_get(
+        ([g.mins for g in pyr.levels], [g.cell_edge for g in pyr.levels]))
+    return all(
+        np.array_equal(mn, mins[0])
+        and np.array_equal(e, edges[0] * np.float32(s**lvl))
+        for lvl, (mn, e) in enumerate(zip(mins, edges))
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("query_block",))
+def finest_counts(level0: grid_lib.GridIndex, queries_r=None, *,
+                  query_block: int = 128) -> jnp.ndarray:
+    """N₀: each query row's 3^m-neighbourhood population on the finest
+    pyramid level, (|Q|,) int32 — the rows of ``queries_r`` (reference-
+    reordered), or every indexed row when it is None (self-join).
+
+    Computed blockwise, ``query_block`` rows per ``lax.map`` step, each
+    reduced to one count per row, so no (|Q|, 3^m) array exists."""
+    if queries_r is None:
+        coords = level0.point_coords
+    else:
+        coords = grid_lib.compute_cell_coords(
+            level0, queries_r[:, : level0.m])
+    n = coords.shape[0]
+    blocks = (jnp.zeros((round_up(n, query_block), level0.m), jnp.int32)
+              .at[:n].set(coords).reshape(-1, query_block, level0.m))
+    out = jax.lax.map(lambda c: grid_lib.neighborhood_counts(level0, c),
+                      blocks)
+    return out.reshape(-1)[:n]
 
 
 class SparseKNNResult(NamedTuple):

@@ -308,6 +308,10 @@ class _Generation:
     calib: Dict[tuple, Tuple[Optional[float], float]] = (
         dataclasses.field(default_factory=dict)
     )
+    # Capacity gate (``KNNIndex._sparse_ok``): whether the pyramid's
+    # levels nest, and the self-cloud's mask once computed.
+    levels_nest: bool = False
+    self_sparse_ok: Optional[np.ndarray] = None
 
     @property
     def n_base(self) -> int:
@@ -371,6 +375,7 @@ class KNNIndex:
             home_counts=home_counts,
             projection=projection,
             points_full=points_full,
+            levels_nest=sparse_lib.levels_nest(pyramid, config.level_scale),
         )
         # Delta rows arrive in the corpus' ORIGINAL (full) dim order.
         mut_dims = (projection.in_dim if projection is not None
@@ -814,6 +819,52 @@ class KNNIndex:
 
     # -- work split --------------------------------------------------------
 
+    def _finest_counts(self, gen: _Generation, queries_rp):
+        """N₀ of the call's query rows (``sparse_lib.finest_counts``),
+        left on the device for the split's host copy; None where the
+        capacity gate is off (levels not proven to nest)."""
+        if not gen.levels_nest:
+            return None
+        return sparse_lib.finest_counts(
+            gen.pyramid.levels[0], queries_rp,
+            query_block=self.config.query_block)
+
+    def _sparse_ok(self, n0, n_q: int) -> Optional[np.ndarray]:
+        """The capacity gate: rows with N₀ ≤ ``sparse_budget``.  Proof:
+        the engine certifies a row only at a level whose 3^m population
+        is within the budget; nested levels make every level's 3^m
+        neighbourhood cover level 0's, so N₀ > budget overflows every
+        level, and the brute lane answers the row either way.
+        On a mutated index the engine and N₀ both count the base grid's
+        rows, tombstoned ones included; delta rows are in neither."""
+        if n0 is None:
+            return None
+        return np.asarray(n0)[:n_q] <= self.config.sparse_budget
+
+    def _self_sparse_ok(self, gen: _Generation) -> Optional[np.ndarray]:
+        """The capacity gate over the indexed cloud itself (cached on
+        the generation, like its home-cell densities)."""
+        if gen.levels_nest and gen.self_sparse_ok is None:
+            gen.self_sparse_ok = self._sparse_ok(
+                self._finest_counts(gen, None), gen.n_base)
+        return gen.self_sparse_ok
+
+    def _query_split(self, gen: _Generation, q_coords, queries_rp,
+                     n_q: int, k: int, rho: float, net_adjust=None):
+        """§V-D split of foreign query rows by the reference grid's
+        density, with the capacity gate's mask, copied to the host in
+        one transfer: ``(dense_ids, sparse_ids, home_counts, threshold,
+        sparse_ok)``."""
+        split = split_lib.split_queries(
+            gen.grid, q_coords, k, self.config.gamma, rho,
+            net_adjust=net_adjust)
+        to_dense, home_counts, threshold, n0 = jax.device_get((
+            split.to_dense, split.home_counts, split.threshold,
+            self._finest_counts(gen, queries_rp)))
+        return (np.nonzero(to_dense)[0].astype(np.int32),
+                np.nonzero(~to_dense)[0].astype(np.int32),
+                home_counts, float(threshold), self._sparse_ok(n0, n_q))
+
     def _self_split(
         self, gen: _Generation, k: int, rho: float
     ) -> Tuple[np.ndarray, np.ndarray, float]:
@@ -930,10 +981,12 @@ class KNNIndex:
 
     def _drain(self, gen: _Generation, kq: int, n_q: int, queries_rp,
                dense_ids, sparse_ids, home_counts, exclude_self: bool,
-               rho: Optional[float] = None):
+               rho: Optional[float] = None,
+               sparse_ok: Optional[np.ndarray] = None):
         """Steps 5–8 of Algorithm 1: the §V-A work queue over the three
-        engines.  Returns SQUARED distances (√ happens after any
-        merge-time folding, so folds compare like with like)."""
+        engines, ``sparse_ok`` its capacity gate (``_sparse_ok``).
+        Returns SQUARED distances (√ happens after any merge-time
+        folding, so folds compare like with like)."""
         cfg = self.config
         rho_floor = cfg.rho if rho is None else rho
         report = queue_lib.QueueReport()
@@ -949,8 +1002,10 @@ class KNNIndex:
         # the buckets its demotions land in.)
         t0 = time.perf_counter()
         with trace.span("knn.prepare"):
-            if len(sparse_ids):
-                sparse_fn(sparse_ids, compile_only=True)
+            first_sparse = (sparse_ids if sparse_ok is None
+                            else sparse_ids[sparse_ok[sparse_ids]])
+            if len(first_sparse):
+                sparse_fn(first_sparse, compile_only=True)
             first = -(-len(dense_ids) // cfg.n_batches)
             if first:
                 dense_fn(dense_ids[:first], compile_only=True)
@@ -970,6 +1025,7 @@ class KNNIndex:
             min_sparse=int(math.ceil(rho_floor * n_q)),
             demote_quantum=cfg.query_block,
             report=report,
+            sparse_ok=sparse_ok,
         )
         report.t_wall += t_prepare      # the query's wall holds its compiles
         return (*answers, report)
@@ -1003,6 +1059,7 @@ class KNNIndex:
             n_rebalanced=report.n_rebalanced,
             n_sparse_rounds=report.n_sparse_rounds,
             n_sparse_engine_total=report.n_sparse_engine_total,
+            n_sparse_gated=report.n_sparse_gated,
             rho_online=report.rho_online,
             n_engine_compiles=self.total_compiles - compiles_before,
             n_dense_overflow=report.n_dense_overflow,
@@ -1099,6 +1156,7 @@ class KNNIndex:
                 dense_ids, sparse_ids, threshold = self._self_split(
                     gen, kq, rho)
                 home_counts = gen.home_counts
+                sparse_ok = self._self_sparse_ok(gen)
             else:
                 q = jnp.asarray(q_np)
                 queries_r = (q[:, gen.dim_perm] if gen.dim_perm is not None
@@ -1117,25 +1175,20 @@ class KNNIndex:
                     net_adjust = jnp.asarray(mut_lib.net_cell_adjustment(
                         gen.grid, q_cells, *_net_cells
                     ))
-                split = split_lib.split_queries(
-                    gen.grid, q_coords, kq, cfg.gamma, rho,
-                    net_adjust=net_adjust,
-                )
-                to_dense = np.asarray(split.to_dense)
-                dense_ids = np.nonzero(to_dense)[0].astype(np.int32)
-                sparse_ids = np.nonzero(~to_dense)[0].astype(np.int32)
-                home_counts = np.asarray(split.home_counts)
-                threshold = float(split.threshold)
+                (dense_ids, sparse_ids, home_counts, threshold,
+                 sparse_ok) = self._query_split(
+                    gen, q_coords, queries_rp, n_q, kq, rho, net_adjust)
 
         if cfg.recall_target < 1.0 and _net_cells is None:
             return self._query_approx(
                 gen, kq, n_q, queries_rp, dense_ids, sparse_ids,
                 home_counts, threshold, exclude_self, rho, compiles_before,
+                sparse_ok,
             )
 
         final_d, final_i, source, report = self._drain(
             gen, kq, n_q, queries_rp, dense_ids, sparse_ids, home_counts,
-            exclude_self, rho=rho,
+            exclude_self, rho=rho, sparse_ok=sparse_ok,
         )
         with trace.span("knn.finalize"):
             stats = self._stats(
@@ -1264,10 +1317,12 @@ class KNNIndex:
         self, gen: _Generation, kq: int, n_q: int, queries_rp,
         dense_ids, sparse_ids, home_counts, threshold: float,
         exclude_self: bool, rho: float, compiles_before: int,
+        sparse_ok: Optional[np.ndarray],
     ) -> "hybrid_lib.KNNResult":
         """recall_target < 1.0 (§9.4): serve the calibrated lean tier —
         or fall back to the exact pipeline (estimate 1.0) when no lean
-        tier met the target on the held-out sample."""
+        tier met the target on the held-out sample.  ``sparse_ok`` gates
+        that pipeline only: the lean tier has no brute lane."""
         from repro.retrieval import calibrate as cal_lib
 
         cfg = self.config
@@ -1275,7 +1330,7 @@ class KNNIndex:
         if eps_scale is None:
             final_d, final_i, source, report = self._drain(
                 gen, kq, n_q, queries_rp, dense_ids, sparse_ids,
-                home_counts, exclude_self, rho=rho,
+                home_counts, exclude_self, rho=rho, sparse_ok=sparse_ok,
             )
         else:
             final_d, final_i, source, report = self._lean_pass(
@@ -1310,20 +1365,17 @@ class KNNIndex:
             dense_ids, sparse_ids, threshold = self._self_split(
                 gen, k_cand, rho)
             home_counts = gen.home_counts
+            sparse_ok = self._self_sparse_ok(gen)
         else:
             q_coords = grid_lib.compute_cell_coords(
                 gen.grid, queries_rp[:n_q, : gen.grid.m]
             )
-            split = split_lib.split_queries(
-                gen.grid, q_coords, k_cand, cfg.gamma, rho)
-            to_dense = np.asarray(split.to_dense)
-            dense_ids = np.nonzero(to_dense)[0].astype(np.int32)
-            sparse_ids = np.nonzero(~to_dense)[0].astype(np.int32)
-            home_counts = np.asarray(split.home_counts)
-            threshold = float(split.threshold)
+            (dense_ids, sparse_ids, home_counts, threshold,
+             sparse_ok) = self._query_split(
+                gen, q_coords, queries_rp, n_q, k_cand, rho)
         cd, ci, source, report = self._drain(
             gen, k_cand, n_q, queries_rp, dense_ids, sparse_ids,
-            home_counts, exclude_self, rho=rho,
+            home_counts, exclude_self, rho=rho, sparse_ok=sparse_ok,
         )
         t0 = time.perf_counter()
         qb = pow2_bucket(n_q, cfg.query_block)
@@ -1465,18 +1517,12 @@ class KNNIndex:
             net_adjust = jnp.asarray(mut_lib.net_cell_adjustment(
                 gen.grid, q_cells, delta_live_r, tomb_pts_r
             ))
-            split = split_lib.split_queries(
-                gen.grid, q_coords, kq, cfg.gamma, cfg.rho,
-                net_adjust=net_adjust,
-            )
-            to_dense = np.asarray(split.to_dense)
-            dense_ids = np.nonzero(to_dense)[0].astype(np.int32)
-            sparse_ids = np.nonzero(~to_dense)[0].astype(np.int32)
-            home_counts = np.asarray(split.home_counts)
-            threshold = float(split.threshold)
+            (dense_ids, sparse_ids, home_counts, threshold,
+             sparse_ok) = self._query_split(
+                gen, q_coords, queries_rp, n_q, kq, cfg.rho, net_adjust)
             final_d, final_i, source, report = self._drain(
                 gen, k_main, n_q, queries_rp, dense_ids, sparse_ids,
-                home_counts, False,
+                home_counts, False, sparse_ok=sparse_ok,
             )
 
         # Delta top-K + fold, through the same AOT engine cache.

@@ -979,6 +979,7 @@ class ShardedKNNIndex:
             n_sparse_rounds=sum(s.n_sparse_rounds for s in shard_stats),
             n_sparse_engine_total=sum(
                 s.n_sparse_engine_total for s in shard_stats),
+            n_sparse_gated=sum(s.n_sparse_gated for s in shard_stats),
             n_dense_overflow=sum(s.n_dense_overflow for s in shard_stats),
             dense_pairs=sum(s.dense_pairs for s in shard_stats),
             dense_cand_rows=sum(s.dense_cand_rows for s in shard_stats),

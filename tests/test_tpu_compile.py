@@ -5,13 +5,19 @@ k=16, 128-row tiles, |D| = 5,000,000) for one device of a described
 ``v5e:2x2`` topology and compiles it with the TPU compiler — no chip,
 no interpret mode — then checks that the program holds the Mosaic
 kernel (``tpu_custom_call``).  This catches what interpret mode cannot:
-block shapes Mosaic refuses, and kernel bodies it cannot lower."""
+block shapes Mosaic refuses, and kernel bodies it cannot lower.  The
+sparse capacity gate's count program compiles beside them, held to its
+memory at CHist's size."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import grid as grid_lib
+from repro.core import sparse_knn as sparse_lib
 from repro.core.dense_join import PREFETCH_BLOCK_SLACK
 from repro.kernels.bin_hist import kernel as hist_kernel
 from repro.kernels.knn_stream import kernel as stream_kernel
@@ -124,3 +130,23 @@ def test_pairwise_dyn_shortc_compiles(compile_for_chip):
         ((BLOCK, BLOCK), jnp.float32),
         ((2048, BLOCK), jnp.float32),
         ((), jnp.float32))
+
+
+@pytest.mark.parametrize("foreign", [True, False], ids=["foreign", "self"])
+def test_finest_counts_compiles_small(one_chip, no_compile_cache, foreign):
+    """N₀ over CHist (68,040 x 32, m = 6) for an 8,192-row batch or the
+    whole corpus: blockwise, its temporaries stay under 16 MiB."""
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    level0 = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+        functools.partial(grid_lib.build_grid, m=6,
+                          materialize_points=False),
+        jax.ShapeDtypeStruct((68_040, 32), jnp.float32),
+        jax.ShapeDtypeStruct((), jnp.float32)))
+    args = (level0,)
+    if foreign:
+        args += (on_chip(jax.ShapeDtypeStruct((8192, 32), jnp.float32)),)
+    compiled = sparse_lib.finest_counts.lower(
+        *args, query_block=BLOCK).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
